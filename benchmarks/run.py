@@ -1296,9 +1296,12 @@ def main() -> None:
     unknown = [n for n in names if n not in ALL]
     if unknown:
         raise SystemExit(f"unknown bench(es) {unknown}; choose from {list(ALL)}")
+    from repro.core.device import enable_compile_cache
+    from repro.fabric.telemetry import telemetry_session
+
+    enable_compile_cache()
     print("name,us_per_call,derived")
     config = _bench_config()
-    from repro.fabric.telemetry import telemetry_session
 
     for n in names:
         r0, d0 = len(_JSON_ROWS), len(_JSON_DETAILS)

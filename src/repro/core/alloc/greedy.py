@@ -517,7 +517,7 @@ def greedy_allocate_batch(
     (N,) to (C, N); ``budgets`` is (C,).  Replica counts are element-wise
     identical to looping the scalar allocator (the property suite pins
     this); ``spent`` / ``leftover`` agree to float roundoff.  Runs in
-    float64 under ``jax.experimental.enable_x64``.
+    float64 under ``core.precision.x64``.
     """
     budgets = np.atleast_1d(np.asarray(budgets, dtype=np.float64))
     C = budgets.shape[0]
@@ -543,11 +543,11 @@ def greedy_allocate_batch(
             np.ones((C, 0), dtype=np.int64), base.copy(), np.zeros(C), budgets.copy()
         )
 
-    from jax.experimental import enable_x64
+    from ..precision import x64
 
     if "kernel" not in _GREEDY_BATCH_JIT:
         _GREEDY_BATCH_JIT["kernel"] = _greedy_batch_kernel()
-    with enable_x64():
+    with x64():
         r, rem = _GREEDY_BATCH_JIT["kernel"](base, cost, budgets, r0)
     r = np.asarray(r)
     replicas = r.astype(np.int64)

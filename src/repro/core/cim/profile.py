@@ -120,7 +120,9 @@ def synthetic_images(n: int, hw: int, key: jax.Array, channels: int = 3) -> jax.
 
 
 def _im2col(x: jax.Array, layer: LayerSpec) -> jax.Array:
-    """(N,H,W,C) -> (P, rows) patch matrix for this conv layer."""
+    """(N,H,W,C) -> (P, rows) patch matrix for this conv layer.  Full
+    float32 precision: at the TPU's default the patch extraction (a
+    convolution) would round every activation to bfloat16."""
     pad = "SAME" if layer.kernel > 1 else "VALID"
     patches = jax.lax.conv_general_dilated_patches(
         x,
@@ -128,6 +130,7 @@ def _im2col(x: jax.Array, layer: LayerSpec) -> jax.Array:
         (layer.stride, layer.stride),
         pad,
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=jax.lax.Precision.HIGHEST,
     )  # (N, H', W', C*k*k)
     rows = patches.shape[-1]
     assert rows == layer.rows, (rows, layer.rows, layer.name)
@@ -167,7 +170,7 @@ class _CaptureTracer:
         layer = self.spec.layers[idx]
         pat = jax.nn.relu(_im2col(x, layer))  # (P, rows) float32, >= 0
         # per-tensor uint8 quantization: the scale is computed in float64
-        # (this traces under enable_x64) and applied in float32 — the same
+        # (this traces under precision.x64) and applied in float32 — the same
         # arithmetic the host-side `float(jnp.max(x))` path performed
         scale = jnp.max(pat).astype(jnp.float64) / 255.0 + 1e-12
         s32 = scale.astype(jnp.float32)
@@ -184,7 +187,10 @@ class _CaptureTracer:
             jnp.zeros((layer.rows,), jnp.int64),
         )
         self.sampled[idx] = jnp.take(q, self.sel[idx], axis=0)
-        y = (q.astype(jnp.float32) * s32) @ self.weights[idx]
+        y = jnp.matmul(
+            q.astype(jnp.float32) * s32, self.weights[idx],
+            precision=jax.lax.Precision.HIGHEST,
+        )
         n = x.shape[0]
         return y.reshape(n, layer.out_hw, layer.out_hw, layer.cout)
 
@@ -260,15 +266,20 @@ def capture_activations(
     # only), but ``spec`` is the jit static argument — canonicalize it so
     # every ArrayConfig variant of a network shares one compiled forward
     spec = with_array(spec, DEFAULT_ARRAY)
-    key = jax.random.PRNGKey(seed)
-    kimg, kw = jax.random.split(key)
     if image_hw is None:
         image_hw = 224 if spec.name == "resnet18" else 32
-    keys = jax.random.split(kw, len(spec.layers))
-    weights = tuple(
-        _kaiming(keys[i], l.rows, l.cout) for i, l in enumerate(spec.layers)
-    )
-    x = synthetic_images(n_images, image_hw, kimg)
+    # the seeded weights and images are made on the host CPU, so that every
+    # backend profiles the same network on the same inputs (an accelerator's
+    # own random-normal and resize kernels round differently)
+    with jax.default_device(jax.devices("cpu")[0]):
+        key = jax.random.PRNGKey(seed)
+        kimg, kw = jax.random.split(key)
+        keys = jax.random.split(kw, len(spec.layers))
+        weights = tuple(
+            np.asarray(_kaiming(keys[i], l.rows, l.cout))
+            for i, l in enumerate(spec.layers)
+        )
+        x = np.asarray(synthetic_images(n_images, image_hw, kimg))
 
     # sample patch indices over the FULL calibration run, one rng stream in
     # layer order (the legacy profiler's exact draw sequence)
@@ -286,7 +297,7 @@ def capture_activations(
         np.zeros((t, l.rows), dtype=np.uint8) for t, l in zip(takes, spec.layers)
     ]
     batch = n_images if batch_images is None else max(1, min(batch_images, n_images))
-    from jax.experimental import enable_x64
+    from ..precision import x64
 
     for i0 in range(0, n_images, batch):
         i1 = min(i0 + batch, n_images)
@@ -298,7 +309,7 @@ def capture_activations(
             loc = sg - off
             owned.append((loc >= 0) & (loc < pb))
             sel_local.append(jnp.asarray(np.clip(loc, 0, pb - 1).astype(np.int32)))
-        with enable_x64():
+        with x64():
             rb, qs = _capture_jit(spec, weights, tuple(sel_local), x[i0:i1])
         for li in range(L):
             rowbits[li] += np.asarray(rb[li])
@@ -398,7 +409,7 @@ def _derive_layer_pallas(
     """Cycle samples via the Pallas bit-plane popcount kernel
     (``kernels.bitplane_profile``; interpret-mode off-TPU)."""
     from ...kernels.bitplane_profile import bitplane_profile
-    from ...kernels.ops import interpret_mode
+    from ..device import interpret_mode
 
     starts, stops = _slice_bounds(layer)
     _, cyc = bitplane_profile(

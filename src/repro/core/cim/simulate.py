@@ -431,8 +431,14 @@ def _eval_kernel(
     busy = busy_sum * P * width
     T = layer_T.max()
     util = busy / (alive * T)
-    ips = n_images / (T / clock_hz)
-    return T, ips, layer_T, util
+    return T, images_per_sec(T, n_images, clock_hz), layer_T, util
+
+
+def images_per_sec(T, n_images, clock_hz):
+    """Throughput from total cycles.  Jitted callers apply this on the host
+    to the device's ``T``: XLA rewrites ``a / (b / c)`` into ``(a * c) / b``,
+    which moves the last bit away from the numpy reference."""
+    return n_images / (T / clock_hz)
 
 
 def _alloc_to_dups(st: SimTensors, alloc: Allocation) -> tuple[np.ndarray, bool]:
@@ -497,8 +503,8 @@ class BatchSimulator:
     """jit + vmap of ``_eval_kernel`` over a batch of allocations.
 
     One instance per (spec, profile); the packed tensors are baked into the
-    compiled kernel as constants.  Runs in float64 (``jax.experimental
-    .enable_x64``) so batch results match the scalar ``simulate()`` to
+    compiled kernel as constants.  Runs in float64 (``core.precision
+    .x64``) so batch results match the scalar ``simulate()`` to
     roundoff — the golden-equivalence suite pins this at 1e-9.
 
     ``shard=True`` shard_maps the vmapped kernel over the host's local
@@ -524,7 +530,7 @@ class BatchSimulator:
 
             def one(dups_lb, layerwise, zskip):
                 pick = lambda a: jnp.where(zskip, a[1], a[0])  # noqa: E731
-                return _eval_kernel(
+                T, _, layer_T, util = _eval_kernel(
                     jnp,
                     pick(st.mean_b),
                     pick(st.max_b),
@@ -540,6 +546,7 @@ class BatchSimulator:
                     n_images,
                     clock_hz,
                 )
+                return T, layer_T, util
 
             if self.shard:
                 from ...distrib.sharding import shard_map_batch
@@ -557,19 +564,21 @@ class BatchSimulator:
         n_images: int = 64,
         clock_hz: float = CLOCK_HZ,
     ) -> BatchSimResult:
-        from jax.experimental import enable_x64
+        from ..precision import x64
 
         dups_lb = np.asarray(dups_lb, dtype=np.float64)
         if dups_lb.ndim != 3 or dups_lb.shape[1:] != (self.tensors.L, self.tensors.B):
             raise ValueError(
                 f"dups_lb {dups_lb.shape} != (C, {self.tensors.L}, {self.tensors.B})"
             )
-        with enable_x64():
-            T, ips, layer_T, util = self._fn(int(n_images), float(clock_hz))(
+        with x64():
+            T, layer_T, util = self._fn(int(n_images), float(clock_hz))(
                 dups_lb, np.asarray(layerwise, bool), np.asarray(zskip, bool)
             )
+        T = np.asarray(T)
         return BatchSimResult(
-            np.asarray(T), np.asarray(ips), np.asarray(layer_T), np.asarray(util)
+            T, images_per_sec(T, n_images, clock_hz), np.asarray(layer_T),
+            np.asarray(util),
         )
 
 

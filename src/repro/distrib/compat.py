@@ -1,10 +1,7 @@
-"""Version-portable shard_map.
+"""shard_map with this repo's defaults.
 
-``jax.shard_map`` (axis_names= / check_vma=) landed after 0.4.x; older
-releases only have ``jax.experimental.shard_map.shard_map`` with the
-``auto=`` / ``check_rep=`` spelling.  Callers here always name the manual
-axes explicitly, so the translation is mechanical: auto = mesh axes minus
-the manual set.
+Callers name the manual axes explicitly (all mesh axes when omitted) and
+replication checking is off unless asked for.
 """
 
 from __future__ import annotations
@@ -16,24 +13,11 @@ __all__ = ["shard_map"]
 
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None, check_vma=False):
     manual = frozenset(axis_names) if axis_names is not None else frozenset(mesh.axis_names)
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            axis_names=manual,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    auto = frozenset(mesh.axis_names) - manual
-    kwargs = {"auto": auto} if auto else {}
-    return _shard_map(
+    return jax.shard_map(
         f,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
-        check_rep=check_vma,
-        **kwargs,
+        axis_names=manual,
+        check_vma=check_vma,
     )

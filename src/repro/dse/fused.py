@@ -83,6 +83,7 @@ from ..core.cim.simulate import (
     ARRAYS_PER_PE,
     CLOCK_HZ,
     _eval_kernel,
+    images_per_sec,
 )
 from ..core.cim.topology import allocate_placed, stage_transfer_matrix
 from .sweep import (
@@ -112,6 +113,19 @@ _KIND["perf_layerwise"] = 1
 _KIND["blockwise"] = 2
 
 _PIPELINE_CACHE: dict[tuple, "FusedPipeline"] = {}
+
+
+def _check_engine(engine: str) -> None:
+    """Reject an unknown engine, and ``"pallas"`` on a TPU, before any
+    capture or derive work is spent."""
+    if engine not in ("xla", "pallas"):
+        raise ValueError(f"unknown engine {engine!r}; use 'xla' or 'pallas'")
+    if engine == "pallas":
+        from ..core.device import interpret_mode
+        from ..kernels.fused_alloc_eval import PALLAS_ON_TPU
+
+        if not interpret_mode():
+            raise NotImplementedError(PALLAS_ON_TPU)
 
 
 def _canonical(array: ArrayConfig) -> ArrayConfig:
@@ -233,7 +247,7 @@ class FusedPipeline:
 
     # --------------------------------------------- stage 1: shared bank stacks
     def _stats(self, return_bank: bool = False):
-        """Per-group SHARED statistic stacks, derived in-graph ONCE and kept
+        """Per-group SHARED statistic stacks, derived ONCE and kept
         device-resident across every chunk of every call.
 
         Returns ``(mean_s, max_s (2A, L, B), pmn_s, pmx_s, busy_s (2A, L),
@@ -241,18 +255,21 @@ class FusedPipeline:
         variants occupy stack slots [0, A) and the zero-skip derivations
         slots [A, 2A), so a per-config scalar ``sel = a_idx + A*zskip``
         picks a variant *inside* ``_eval_kernel`` — no per-config (L, B)
-        bank is ever materialized.  Derivation (popcount + multi-ADC
-        re-costing + reductions) is bit-equal to the staged
-        ``_pack_profile`` statistics: integer-valued sums are exact in any
-        order and each division happens once."""
+        bank is ever materialized.  The device runs the popcount, the
+        multi-ADC re-costing and the sums and maxima over samples, all in
+        int32 (exact on every backend); the few divisions by the sample
+        count then run on the host, with the very ops ``_pack_profile``
+        applies, so the stacks are bit-equal to the staged statistics.
+        (A compiled division by a constant is not: XLA turns it into a
+        multiply by the reciprocal.)"""
         key = bool(return_bank)
         cached = getattr(self, "_stats_cache", {})
         if key in cached:
             return cached[key]
         import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
 
+        from ..core.precision import x64
         from ..kernels.bitplane_profile import bitplane_cycle_bank
 
         rows_per_read = tuple(v.rows_per_read for v in self.variants)
@@ -260,40 +277,50 @@ class FusedPipeline:
         s_mask, b_mask, s_count, ppi = (
             self.s_mask, self.b_mask, self.s_count, self.ppi,
         )
-        l_idx, blk_idx = self.l_idx, self.blk_idx
 
         def derive(Q):
             bank = bitplane_cycle_bank(
                 Q, rows_per_read, cycles_per_read=cpr
             )  # (A, L, B, S) int32
             valid = s_mask[None, :, None, :] & b_mask[None, :, :, None]
-            cyc = jnp.where(valid, bank, 0).astype(jnp.float64)
-            cyc = jnp.swapaxes(cyc, 2, 3)  # (A, L, S, B), 0-padded
-            mean_b1 = cyc.sum(axis=2) / s_count[None, :, None]  # (A, L, B)
-            max_b1 = cyc.max(axis=2)
-            pmax1 = jnp.where(b_mask[None, :, None, :], cyc, -jnp.inf).max(axis=3)
-            pm_mean1 = (
-                jnp.where(s_mask, pmax1, 0.0).sum(axis=2) / s_count[None, :]
+            cyc = jnp.swapaxes(jnp.where(valid, bank, 0), 2, 3)  # (A, L, S, B)
+            # padded samples and blocks hold 0, below every real cycle count
+            pmax = cyc.max(axis=3)  # (A, L, S) slowest block per patch
+            sums = (
+                cyc.sum(axis=2, dtype=jnp.int32),
+                cyc.max(axis=2),
+                pmax.sum(axis=2, dtype=jnp.int32),
+                pmax.max(axis=2),
             )
-            pm_max1 = jnp.where(s_mask, pmax1, -jnp.inf).max(axis=2)
-            busy1 = jnp.where(b_mask[None], mean_b1, 0.0).sum(axis=2)
-            # baseline stacked under zskip: slot v, slot A+v per ADC index v
-            stats = (
-                jnp.concatenate([jnp.asarray(self.mean0), mean_b1]),
-                jnp.concatenate([jnp.asarray(self.max0), max_b1]),
-                jnp.concatenate([jnp.asarray(self.pm_mean0), pm_mean1]),
-                jnp.concatenate([jnp.asarray(self.pm_max0), pm_max1]),
-                jnp.concatenate([jnp.asarray(self.busy0), busy1]),
-                pm_mean1 * ppi[None, :],  # per-ADC perf_layerwise bases
-                (mean_b1 * ppi[None, :, None])[:, l_idx, blk_idx],  # blockwise
-            )
-            return stats + (cyc,) if return_bank else stats
+            return sums + (cyc,) if return_bank else sums
 
-        with enable_x64():
-            out = jax.jit(derive)(jnp.asarray(self.Q))
-        cached[key] = out
+        with x64():
+            out = [np.asarray(o) for o in jax.jit(derive)(jnp.asarray(self.Q))]
+        sum_b1, max_b1, pm_sum1, pm_max1 = (o.astype(np.float64) for o in out[:4])
+        mean_b1 = sum_b1 / s_count[None, :, None]  # (A, L, B)
+        pm_mean1 = pm_sum1 / s_count[None, :]
+        busy1 = np.where(b_mask[None], mean_b1, 0.0).sum(axis=2)
+        # baseline stacked under zskip: slot v, slot A+v per ADC index v
+        with x64():
+            stats = tuple(
+                jnp.asarray(np.concatenate([s0, s1]))
+                for s0, s1 in (
+                    (self.mean0, mean_b1),
+                    (self.max0, max_b1),
+                    (self.pm_mean0, pm_mean1),
+                    (self.pm_max0, pm_max1),
+                    (self.busy0, busy1),
+                )
+            )
+        stats += (
+            pm_mean1 * ppi[None, :],  # per-ADC perf_layerwise bases
+            (mean_b1 * ppi[None, :, None])[:, self.l_idx, self.blk_idx],  # blockwise
+        )
+        if return_bank:
+            stats += (out[4].astype(np.float64),)
+        cached[key] = stats
         self._stats_cache = cached
-        return out
+        return stats
 
     # ------------------------------------------- stage 2: schedule lookups
     def _schedule(self, kind: int, a: int, max_budget: float):
@@ -362,13 +389,13 @@ class FusedPipeline:
                 n_images=n_images,
                 clock_hz=clock_hz,
             )
-            T, ips, layer_T, util = jax.vmap(
+            T, _, layer_T, util = jax.vmap(
                 lambda s, d, lw: eval_one(
                     mean_s, max_s, pmn_s, pmx_s, busy_s,
                     dups_lb=d, layerwise=lw, sel=s,
                 )
             )(sel, dups_lb, layerwise)
-            return T, ips, layer_T, util, dups_lb
+            return T, layer_T, util, dups_lb
 
         stats = self._stats()[:5]
         if self.shard:
@@ -386,8 +413,9 @@ class FusedPipeline:
             # fresh chunks through one program, so XLA reuses the buffer
             # instead of growing the live set per dispatch
             donate = (3,) if fam == "L" else ()
-            jitted = jax.jit(fused, donate_argnums=donate)
-            self._compiled[key] = lambda *a, _j=jitted, _s=stats: _j(_s, *a)
+            self._compiled[key] = functools.partial(
+                jax.jit(fused, donate_argnums=donate), stats
+            )
         return self._compiled[key]
 
     def _validate(self, policies, n_pes):
@@ -442,15 +470,17 @@ class FusedPipeline:
         allocate+eval Pallas kernel (``kernels.fused_alloc_eval``): the
         greedy runs IN-kernel against the per-variant bases (proportional
         configs ride along at budget 0 with their replicas as warm start)
-        — the dense-grid TPU regime, interpret-mode fallback off-TPU.
-        Results are element-wise identical on the discrete columns and
-        within the rtol 1e-12 contract on floats (pinned by
+        — interpret mode, off-TPU only: on a TPU it raises
+        ``PALLAS_ON_TPU`` (the kernel's float64 contract does not lower
+        to Mosaic).  Results are element-wise identical on the discrete
+        columns and within the rtol 1e-12 contract on floats (pinned by
         tests/test_fused_dse.py).
         """
-        from jax.experimental import enable_x64
+        from ..core.precision import x64
 
         from ..fabric.telemetry import get_telemetry
 
+        _check_engine(engine)
         policies, n_pes, total = self._validate(policies, n_pes)
         a_idx = np.broadcast_to(
             np.atleast_1d(np.asarray(a_idx, dtype=np.int32)), policies.shape
@@ -486,8 +516,6 @@ class FusedPipeline:
                 int(n_images), float(clock_hz), int(chunk), need_dups,
                 return_bank,
             )
-        if engine != "xla":
-            raise ValueError(f"unknown engine {engine!r}; use 'xla' or 'pallas'")
         used_f = np.zeros(C)
         rows_B = np.nonzero(kind == 2)[0]
         r_blk = np.ones((rows_B.size, self.N))  # family "B", rows_B order
@@ -510,7 +538,6 @@ class FusedPipeline:
 
         outs = {
             "total_cycles": np.zeros(C),
-            "images_per_sec": np.zeros(C),
             "layer_cycles": np.zeros((C, self.L)),
             "layer_utilization": np.zeros((C, self.L)),
         }
@@ -518,7 +545,7 @@ class FusedPipeline:
             outs["dups_lb"] = np.zeros((C, self.L, self.B))
         tel = get_telemetry()
         csize_max = n_chunks = 0
-        with enable_x64():
+        with x64():
             for fam, rows, r_fam in (("L", rows_L, r_layer), ("B", rows_B, r_blk)):
                 if rows.size == 0:
                     continue
@@ -543,16 +570,18 @@ class FusedPipeline:
                             r_take = np.concatenate(
                                 [r_take, np.repeat(r_take[:1], pad, axis=0)]
                             )
-                    T, ips, layer_T, util, dups = fn(
+                    T, layer_T, util, dups = fn(
                         sel[take], layerwise[take], r_take
-                    )[:5]
+                    )
                     outs["total_cycles"][part] = np.asarray(T)[: part.size]
-                    outs["images_per_sec"][part] = np.asarray(ips)[: part.size]
                     outs["layer_cycles"][part] = np.asarray(layer_T)[: part.size]
                     outs["layer_utilization"][part] = np.asarray(util)[: part.size]
                     if need_dups:
                         outs["dups_lb"][part] = np.asarray(dups)[: part.size]
                     n_chunks += 1
+        outs["images_per_sec"] = images_per_sec(
+            outs["total_cycles"], n_images, clock_hz
+        )
         outs["arrays_used"] = self.base_arrays + used_f.astype(np.int64)
         # chunking telemetry: the live device set per dispatch is one tile —
         # the (csize, L, B) replica tensor dominates — never the full C
@@ -583,7 +612,7 @@ class FusedPipeline:
         Proportional configs enter at budget 0 with their host-precomputed
         replicas as the warm start (the greedy is then a no-op), so one
         kernel serves every supported policy."""
-        from jax.experimental import enable_x64
+        from ..core.precision import x64
 
         from ..kernels.fused_alloc_eval import fused_alloc_eval
         from .engine import flat_unit_map
@@ -593,7 +622,6 @@ class FusedPipeline:
         C = budgets.shape[0]
         outs = {
             "total_cycles": np.zeros(C),
-            "images_per_sec": np.zeros(C),
             "layer_cycles": np.zeros((C, self.L)),
             "layer_utilization": np.zeros((C, self.L)),
         }
@@ -606,7 +634,7 @@ class FusedPipeline:
             ("B", np.nonzero(kind == 2)[0], np.asarray(stats[6]),
              self.cost_blk, flat_unit_map(self.L, self.B, self.l_idx, self.blk_idx)),
         )
-        with enable_x64():
+        with x64():
             for fam, rows, base, cost, umap in fams:
                 if rows.size == 0:
                     continue
@@ -620,7 +648,7 @@ class FusedPipeline:
                 for j0 in range(0, rows.size, csize):
                     part = rows[j0 : j0 + csize]
                     sl = slice(j0, j0 + part.size)
-                    T, ips, layer_T, util, r, _ = fused_alloc_eval(
+                    T, _, layer_T, util, r, _ = fused_alloc_eval(
                         base, cost, umap, banks, self.b_mask, self.ppi,
                         self.width, self.layer_arrays, bud[sl], a_idx[part],
                         sel[part], layerwise[part], r0[sl],
@@ -628,7 +656,6 @@ class FusedPipeline:
                         block_configs=min(csize, 128),
                     )
                     outs["total_cycles"][part] = np.asarray(T)
-                    outs["images_per_sec"][part] = np.asarray(ips)
                     outs["layer_cycles"][part] = np.asarray(layer_T)
                     outs["layer_utilization"][part] = np.asarray(util)
                     r = np.asarray(r)
@@ -644,6 +671,9 @@ class FusedPipeline:
                             d = np.ones((part.size, self.L, self.B))
                             d[:, self.l_idx, self.blk_idx] = r
                             outs["dups_lb"][part] = d
+        outs["images_per_sec"] = images_per_sec(
+            outs["total_cycles"], n_images, clock_hz
+        )
         outs["arrays_used"] = self.base_arrays + used_f.astype(np.int64)
         outs["arrays_total"] = total
         outs["layerwise"] = layerwise
@@ -653,8 +683,10 @@ class FusedPipeline:
         return outs
 
     # ----------------------------------------------------- fused fabric stage
-    def _fabric_fn(self, n, D_by_layer, percentiles, has_xfer, window):
-        key = (n, tuple(D_by_layer), tuple(percentiles), has_xfer, window)
+    def _fabric_fn(self, n, D_by_layer, has_xfer, window):
+        """Cached jit(vmap) of the virtual-time kernel over configs; times
+        are int64 bit patterns (``core.precision``), exact on any backend."""
+        key = (n, tuple(D_by_layer), has_xfer, window)
         if key in self._fabric_compiled:
             return self._fabric_compiled[key]
         import functools
@@ -662,11 +694,12 @@ class FusedPipeline:
         import jax
         import jax.numpy as jnp
 
+        from ..core.precision import to_bits
         from ..fabric.vtime import run_fabric_kernel
 
-        cyc_banks = self._cyc_banks  # per layer (A, S_l, B_l) float64
+        cyc_banks = [to_bits(c) for c in self._cyc_banks]  # per layer (A, S_l, B_l)
         base_banks = [
-            self.baseline_lb[:, li, : layer.n_blocks]
+            to_bits(self.baseline_lb[:, li, : layer.n_blocks])
             for li, layer in enumerate(self.spec.layers)
         ]  # per layer (A, B_l)
         job_scan = functools.partial(jax.lax.scan, unroll=1)
@@ -686,7 +719,7 @@ class FusedPipeline:
                 # staged per-group (S, 1) packing — max commutes with the
                 # service-index gather)
                 c_lw = jnp.where(
-                    onehot0[None, :], c.max(axis=1, keepdims=True), 0.0
+                    onehot0[None, :], c.max(axis=1, keepdims=True), 0
                 )
                 stages.append(
                     (
@@ -702,7 +735,6 @@ class FusedPipeline:
                 arrivals,
                 idx,
                 None,
-                tuple(percentiles),
                 job_scan=job_scan,
                 xfer=xfer,
                 window=window,
@@ -754,8 +786,7 @@ class FusedPipeline:
         ``window`` dispatches that many requests per ``lax.scan`` step (the
         blocked scan; non-overtaking makes any window bit-identical to
         ``window=1``, so this is purely a host-overhead knob)."""
-        from jax.experimental import enable_x64
-
+        from ..core.precision import from_bits, to_bits, x64
         from ..fabric.vtime import sample_service_indices
 
         C, n = arrival_times.shape
@@ -790,7 +821,7 @@ class FusedPipeline:
                 subs.append([int(j)])
         q = max(1, int(lane_quantum))
         pcts = np.zeros((C, len(qs)))
-        with enable_x64():
+        with x64():
             for rows in subs:
                 r = np.asarray(rows)
                 frees = []
@@ -801,19 +832,18 @@ class FusedPipeline:
                         np.where(np.arange(D) < d[:, :, None], 0.0, np.inf)
                     )
                 fn = self._fabric_fn(
-                    n, [f.shape[2] for f in frees], qs, xfer is not None,
-                    int(window),
+                    n, [f.shape[2] for f in frees], xfer is not None, int(window)
                 )
                 out = fn(
-                    tuple(frees),
-                    None if xfer is None else xfer[r],
-                    arrival_times[r],
+                    tuple(to_bits(f) for f in frees),
+                    None if xfer is None else to_bits(xfer[r]),
+                    to_bits(arrival_times[r]),
                     a_idx[r],
                     z[r],
                     lw[r],
                     tuple(idx),
                 )
-                t_arr, comp = np.asarray(out[0]), np.asarray(out[1])
+                t_arr, comp = from_bits(out[0]), from_bits(out[1])
                 # percentiles recomputed host-side from the bit-exact
                 # latencies, matching the staged sweep columns exactly
                 pcts[r] = np.percentile(comp - t_arr, qs, axis=1).T
@@ -890,6 +920,7 @@ def run_fused_sweep(
     policy is load-coupled and stays staged.  ``engine="pallas"`` routes
     the analytic stage through the fused allocate+eval Pallas kernel (see
     ``FusedPipeline.__call__``)."""
+    _check_engine(engine)
     if chunk_size is not None:
         chunk = int(chunk_size)
     C = len(points)

@@ -56,6 +56,7 @@ from ..core.cim.simulate import (
     blockwise_units,
     split_block_dups,
 )
+from ..core.precision import add, from_bits, sub, to_bits, x64
 from .arrivals import ArrivalProcess, ClosedLoop, arrival_times
 from .drift import DriftConfig
 from .metrics import (
@@ -125,7 +126,7 @@ def _stream_request_step(
     new_frees = []
     for li, ((cycles, b_mask), free) in enumerate(zip(stages, frees)):
         if xfer is not None:
-            t = t + xfer[li]
+            t = add(xp, t, xfer[li])
         n_samples, ppi = dims[li]
         ix = hash_service_indices(xp, salts[li], r, ppi, n_samples)
         svc = _chunk_services(xp, cycles[ix], plans[li])
@@ -136,7 +137,7 @@ def _stream_request_step(
     new = (
         tuple(new_frees),
         ring,
-        sketch_update(xp, sk, t - t0, cfg),
+        sketch_update(xp, sk, sub(xp, t, t0), cfg),
         xp.maximum(horizon, t),
     )
     return _tree_where(xp, i < n_valid, new, carry), ((t0, t) if emit else None)
@@ -180,7 +181,7 @@ def _stream_runner(
     """Cached jit(vmap) of the streaming kernel for one group structure.
     Lane state / ring / sketch state / r0 / n_valid are traced arguments, so
     segmented replay reuses ONE compiled kernel for every same-length
-    (padded) segment."""
+    (padded) segment.  Times are int64 bit patterns (``core.precision``)."""
     key = (
         "fleet", g.layerwise, g.zskip, concurrency, n_pad, window, cfg, plans,
         tuple(f.shape[1:] for f in g.frees), seed, has_xfer, emit,
@@ -191,7 +192,7 @@ def _stream_runner(
         import jax
         import jax.numpy as jnp
 
-        np_stages = g.stages
+        np_stages = tuple((to_bits(c), m) for c, m in g.stages)
         job_scan = functools.partial(jax.lax.scan, unroll=1)
 
         def one(frees, xfer, arrivals, ring, sk, hor, r0, n_valid):
@@ -246,21 +247,28 @@ def _stream_group_call(
         )
     frees, ring, sk, hor = state
     if engine == "jax":
-        from jax.experimental import enable_x64
-
         fn = _stream_runner(
             vt, g, concurrency, n_pad, window, cfg, plans, dims, salts, seed,
             g.xfer is not None, emit,
         )
-        with enable_x64():
-            out = fn(frees, g.xfer, times, ring, sk, hor, r0, n)
+
+        def sk_times(sk, conv):  # the sketch's min and max are times
+            return tuple(conv(a) if i in (2, 3) else np.asarray(a) for i, a in enumerate(sk))
+
+        with x64():
+            out = fn(
+                tuple(to_bits(f) for f in frees),
+                None if g.xfer is None else to_bits(g.xfer),
+                to_bits(times), to_bits(ring), sk_times(sk, to_bits),
+                to_bits(hor), r0, n,
+            )
         if emit:
             out, ys = out
-            comp = (np.asarray(ys[0])[:, :n], np.asarray(ys[1])[:, :n])
-        frees = tuple(np.asarray(f) for f in out[0])
-        ring = np.asarray(out[1])
-        sk = tuple(np.asarray(a) for a in out[2])
-        hor = np.asarray(out[3])
+            comp = (from_bits(ys[0])[:, :n], from_bits(ys[1])[:, :n])
+        frees = tuple(from_bits(f) for f in out[0])
+        ring = from_bits(out[1])
+        sk = sk_times(out[2], from_bits)
+        hor = from_bits(out[3])
         state = (frees, ring, sk, hor)
         return (state, comp) if emit else state
     new_frees = [np.empty_like(f) for f in frees]
@@ -733,29 +741,23 @@ def run_trace_segments(
         idx_s = tuple(ix[lo:hi] for ix in idx)
         times_s = times[lo:hi]
         if engine == "jax":
-            from jax.experimental import enable_x64
-
-            fn = vt._jax_runner(
-                g, None, hi - lo, tuple(percentiles), window=window,
-                return_state=True,
-            )
-            with enable_x64():
+            fn = vt._jax_runner(g, None, hi - lo, window=window, return_state=True)
+            with x64():
                 out = fn(
-                    frees, None, np.broadcast_to(times_s, (c_total, hi - lo)),
-                    idx_s,
+                    tuple(to_bits(f) for f in frees), None,
+                    to_bits(np.broadcast_to(times_s, (c_total, hi - lo))), idx_s,
                 )
-            completions[:, lo:hi] = np.asarray(out[1])
-            frees = tuple(np.asarray(f) for f in out[3])
+            completions[:, lo:hi] = from_bits(out[1])
+            frees = tuple(from_bits(f) for f in out[2])
         else:
             new_frees = [np.empty_like(f) for f in frees]
             for k in range(c_total):
                 out = run_fabric_kernel(
                     np, _np_scan, g.stages, tuple(f[k] for f in frees),
-                    times_s, idx_s, None, tuple(percentiles), window=window,
-                    return_state=True,
+                    times_s, idx_s, None, window=window, return_state=True,
                 )
                 completions[k, lo:hi] = out[1]
-                for li, f in enumerate(out[3]):
+                for li, f in enumerate(out[2]):
                     new_frees[li][k] = f
             frees = tuple(new_frees)
     arrivals = np.broadcast_to(times, (c_total, n)).copy()

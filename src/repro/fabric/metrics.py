@@ -7,6 +7,8 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from ..core.precision import is_bits, value
+
 __all__ = [
     "FabricStats",
     "LatencySketch",
@@ -26,15 +28,18 @@ __all__ = [
 def percentile_kernel(xp, lat, qs):
     """Latency percentiles as pure array algebra over the module ``xp``.
 
-    The ONE implementation shared by the scalar accounting path
-    (``latency_stats``, ``xp=numpy``) and the jitted virtual-time fabric
-    kernel (``fabric.vtime.run_fabric_kernel``, ``xp=jax.numpy``), so the
-    in-kernel reduction cannot drift from the reference: both evaluate
-    ``xp.percentile`` (linear interpolation) on the same float64 latencies.
-    ``lat`` may be any shape reduced over its last axis by the caller's
-    convention (1-D here); ``qs`` is a sequence of percentile levels.
-    Callers guard the empty case (percentiles of zero requests are defined
-    as zeros at the result-container level, not here).
+    The ONE implementation behind the scalar accounting path
+    (``latency_stats``) and every virtual-time engine
+    (``VirtualTimeFabric.run_batch`` applies it, with ``xp=numpy``, to the
+    bit-exact latencies its kernels return), so engines cannot drift from
+    the reference.  ``lat`` may be any shape reduced over its last axis by
+    the caller's convention (1-D here); ``qs`` is a sequence of percentile
+    levels.  Callers guard the empty case (percentiles of zero requests are
+    defined as zeros at the result-container level, not here).
+
+    With ``xp=jax.numpy`` the result agrees with numpy to an ulp, not bit
+    for bit: ``jnp.percentile`` interpolates with another formula, and XLA
+    contracts multiply-adds into FMAs.
     """
     return xp.percentile(lat, xp.asarray(qs))
 
@@ -116,10 +121,18 @@ def sketch_bucket(xp, lat, cfg: SketchConfig):
 
     ``frexp`` factors ``v = m * 2**e`` with ``m in [0.5, 1)``; the octave is
     ``e - 1 - min_exp`` and the sub-bucket is ``floor((2m - 1) * F)``, all of
-    it exact float64 arithmetic for ``F`` a power of two.
+    it exact float64 arithmetic for ``F`` a power of two.  On int64 bit
+    patterns (``core.precision``) the same two numbers are the exponent
+    field and the top ``log2 F`` fraction bits, read with integer ops.
     """
     F = cfg.bins_per_octave
-    v = xp.maximum(xp.asarray(lat, dtype=xp.float64), 2.0**cfg.min_exp)
+    lo = 2.0**cfg.min_exp
+    if is_bits(lat):
+        v = xp.maximum(lat, np.float64(lo).view(np.int64))
+        octave = (v >> 52) - (1023 + cfg.min_exp)
+        sub = (v >> (52 - (F.bit_length() - 1))) & (F - 1)
+        return xp.clip((octave * F + sub).astype(xp.int32), 0, cfg.n_bins - 1)
+    v = xp.maximum(xp.asarray(lat, dtype=xp.float64), lo)
     m, e = xp.frexp(v)
     sub = xp.floor((m * 2.0 - 1.0) * F).astype(xp.int32)
     b = (e.astype(xp.int32) - (cfg.min_exp + 1)) * F + sub
@@ -144,21 +157,25 @@ def sketch_update(xp, state, lat, cfg: SketchConfig):
 
     The Welford moment updates are sequential with a fixed operation order,
     so numpy and jit replays of the same latency stream agree bit-for-bit.
+    ``lat`` may be a float64 or an int64 bit pattern (``core.precision``);
+    ``min``/``max`` then stay bit patterns and the moments take its value.
     """
     counts, n, mn, mx, mean, m2 = state
     b = sketch_bucket(xp, lat, cfg)
     counts = counts + (xp.arange(cfg.n_bins) == b)
     n1 = n + 1.0
-    d = lat - mean
+    x = value(xp, lat)
+    d = x - mean
     mean = mean + d / n1
-    m2 = m2 + d * (lat - mean)
+    m2 = m2 + d * (x - mean)
     return (counts, n1, xp.minimum(mn, lat), xp.maximum(mx, lat), mean, m2)
 
 
 @dataclass(frozen=True)
 class LatencySketch:
     """Materialized streaming sketch: quantiles from the histogram (bounded
-    relative error), min/max/mean exact by construction."""
+    relative error), min/max/mean exact by construction (the mean and m2
+    are rounded by the float64 emulation when folded on a TPU)."""
 
     config: SketchConfig
     counts: np.ndarray  # (n_bins,) integer-valued float64

@@ -24,9 +24,11 @@ recurrence can observe, so one FIFO job is "pop lane 0, elementwise
 sorted-insert of the end time" — pure array algebra with no reductions or
 scatters, shared verbatim between the scalar numpy path and the batched jax
 path (``lax.scan`` over jobs and requests, ``vmap`` over (allocation,
-arrival-trace) pairs, jitted in float64).  Both paths perform bit-for-bit
-the same IEEE operations as the ``ServerPool`` event engine, so per-request
-completion times agree exactly (pinned in tests/test_fabric_vtime.py).
+arrival-trace) pairs, jitted on times held as int64 float64 bit patterns,
+``core.precision``, because a TPU only emulates float64).  Both paths
+perform bit-for-bit the same IEEE operations as the ``ServerPool`` event
+engine, so per-request completion times agree exactly (pinned in
+tests/test_fabric_vtime.py).
 
 Service times are presampled request-major (``sample_service_indices``) from
 the profiled per-(patch, block) cycle sample; ``FabricSim`` consumes the
@@ -43,6 +45,7 @@ import numpy as np
 from ..core.cim.network import NetworkSpec
 from ..core.cim.profile import NetworkProfile
 from ..core.cim.simulate import Allocation, CLOCK_HZ, _layer_patch_cycles
+from ..core.precision import add, from_bits, inf, ninf, sub, to_bits, value
 from .arrivals import ArrivalProcess, ClosedLoop, PoissonOpen, arrival_times
 from .metrics import LatencyStats, latency_stats, percentile_kernel, steady_throughput
 
@@ -76,10 +79,11 @@ def dispatch_step(xp, free, svc):
     No reductions, no scatter: the step is pure elementwise algebra, and it
     performs bit-for-bit the same IEEE add (start + svc) as the event
     engine's ``ServerPool``, whose completion times depend only on the same
-    multiset.  Returns (free', end).
+    multiset.  Times are float64 or their int64 bit patterns
+    (``core.precision``).  Returns (free', end).
     """
-    end = free[..., 0] + svc
-    up = xp.concatenate([free[..., 1:], xp.full_like(free[..., :1], xp.inf)], axis=-1)
+    end = add(xp, free[..., 0], svc)
+    up = xp.concatenate([free[..., 1:], xp.full_like(free[..., :1], inf(free))], axis=-1)
     free = xp.minimum(xp.maximum(free, end[..., None]), up)
     return free, end
 
@@ -112,7 +116,7 @@ def pool_dispatch(xp, scan, free, t_ready, svc, b_mask, collect=False):
             return dispatch_step(xp, free, svc_p)
 
         free, ends = scan(job, free, svc)  # (P, B) per-job completion times
-        done = xp.maximum(xp.where(b_mask, ends, -xp.inf).max(), t_ready)
+        done = xp.maximum(xp.where(b_mask, ends, ninf(ends)).max(), t_ready)
         return free, done
 
     def job(state, svc_p):
@@ -122,12 +126,12 @@ def pool_dispatch(xp, scan, free, t_ready, svc, b_mask, collect=False):
         # accumulate queue wait in the carry (a 0-d scalar) rather than
         # emitting a second (B,) scan output: the collect kernel then adds
         # one fused reduction per job instead of doubling the ys traffic
-        acc = acc + xp.where(b_mask, start - t_ready, 0.0).sum()
+        acc = acc + xp.where(b_mask, value(xp, sub(xp, start, t_ready)), 0.0).sum()
         return (free, acc), end
 
     (free, wait), ends = scan(job, (free, xp.zeros(())), svc)
-    done = xp.maximum(xp.where(b_mask, ends, -xp.inf).max(), t_ready)
-    busy = xp.where(b_mask, svc, 0.0).sum()
+    done = xp.maximum(xp.where(b_mask, ends, ninf(ends)).max(), t_ready)
+    busy = xp.where(b_mask, value(xp, svc), 0.0).sum()
     return free, done, busy, wait
 
 
@@ -145,7 +149,7 @@ def pool_dispatch_stream(xp, scan, free, t_ready, svc, b_mask):
     def job(state, svc_p):
         f, acc = state
         f, end = dispatch_step(xp, f, svc_p)
-        acc = xp.maximum(acc, xp.where(b_mask, end, -xp.inf).max())
+        acc = xp.maximum(acc, xp.where(b_mask, end, ninf(end)).max())
         return (f, acc), None
 
     (free, done), _ = scan(job, (free, t_ready), svc)
@@ -200,7 +204,7 @@ def _chunk_services(xp, svc, plan):
     head = svc[: nb * k].reshape((nb, k) + svc.shape[1:])
     acc = head[:, 0]
     for j in range(1, k):
-        acc = acc + head[:, j]
+        acc = add(xp, acc, head[:, j])
     return xp.concatenate([acc, svc[nb * k :]], axis=0)
 
 
@@ -220,7 +224,7 @@ def _request_step(xp, job_scan, stages, xfer, concurrency, collect, carry, inp):
 
     ``collect=True`` carries two extra per-layer tuples of 0-d accumulators
     (busy, wait) through the scan — the jit path's utilization/duty-cycle
-    telemetry, emitted by the same single jit call as the percentiles.
+    telemetry, emitted by the same single jit call as the completions.
     """
     if collect:
         frees, ring, busy, wait = carry
@@ -236,7 +240,7 @@ def _request_step(xp, job_scan, stages, xfer, concurrency, collect, carry, inp):
     new_frees = []
     for li, ((cycles, b_mask), free, ix) in enumerate(zip(stages, frees, idx)):
         if xfer is not None:
-            t = t + xfer[li]
+            t = add(xp, t, xfer[li])
         svc = cycles[ix]  # (P, B) this request's sampled per-block cycles
         if collect:
             free, t, b_l, w_l = pool_dispatch(
@@ -311,12 +315,14 @@ def _scan_windowed(xp, scan, body, carry, xs, n, window):
 
 
 def run_fabric_kernel(
-    xp, scan, stages, frees, arrivals, idx, concurrency, percentiles,
+    xp, scan, stages, frees, arrivals, idx, concurrency,
     job_scan=None, xfer=None, collect_stats=False, window=1, return_state=False,
 ):
-    """Whole-run recurrence: scan ``_request_step`` over requests, then
-    reduce per-request latencies to percentiles — one fused computation in
-    the jax path, a plain loop in the numpy path.  ``job_scan`` (defaults to
+    """Whole-run recurrence: scan ``_request_step`` over requests and
+    return per-request (arrival, completion) times — one fused computation
+    in the jax path, a plain loop in the numpy path.  Latency percentiles
+    are taken from them on the host (``VirtualTimeFabric.run_batch``), in
+    float64 numpy, on every engine.  ``job_scan`` (defaults to
     ``scan``) drives the inner per-job loop; ``xfer`` is this config's (L,)
     stage transfer vector (or None for the flat fabric).
 
@@ -330,14 +336,14 @@ def run_fabric_kernel(
     (service) cycles and queue-wait cycles per layer, accumulated through
     the scan carry.  They reconcile with the event engine's ``PoolStats``
     counters to float64 summation-order tolerance (scalar ``+=`` there vs.
-    ``xp.sum`` here); completions/percentiles are bit-identical either way.
+    ``xp.sum`` here); completions are bit-identical either way.
 
     ``return_state=True`` appends the final (frees, ring) carry to the
     outputs — the hook segmented replay uses to hand lane state across
     control-interval boundaries.
     """
     n = arrivals.shape[0]
-    ring = xp.zeros(concurrency if concurrency is not None else 1)
+    ring = xp.zeros(concurrency if concurrency is not None else 1, dtype=arrivals.dtype)
     from functools import partial
 
     body = partial(
@@ -353,9 +359,7 @@ def run_fabric_kernel(
     carry, (t_arr, comp) = _scan_windowed(
         xp, scan, body, carry0, (xp.arange(n), arrivals, idx), n, window
     )
-    lat = comp - t_arr
-    pct = percentile_kernel(xp, lat, percentiles)
-    out = (t_arr, comp, pct)
+    out = (t_arr, comp)
     if collect_stats:
         out = out + (xp.stack(carry[2]), xp.stack(carry[3]))
     if return_state:
@@ -627,17 +631,18 @@ class VirtualTimeFabric:
         return out
 
     def _jax_runner(
-        self, g: _GroupPack, concurrency, n, percentiles, collect=False,
-        window=1, return_state=False,
+        self, g: _GroupPack, concurrency, n, collect=False, window=1,
+        return_state=False,
     ):
-        """Cached jit(vmap) of the shared kernel for one group structure."""
+        """Cached jit(vmap) of the shared kernel for one group structure.
+        Times go in and come out as int64 bit patterns (``core.precision``):
+        exact float64 arithmetic on every backend, the TPU included."""
         has_xfer = g.xfer is not None
         key = (
             g.layerwise,
             g.zskip,
             concurrency,
             n,
-            percentiles,
             tuple(f.shape[1:] for f in g.frees),
             has_xfer,
             collect,  # stats-on kernels compile separately (extra outputs)
@@ -650,20 +655,20 @@ class VirtualTimeFabric:
             import jax
             import jax.numpy as jnp
 
-            np_stages = g.stages
+            np_stages = tuple((to_bits(c), m) for c, m in g.stages)
             job_scan = functools.partial(jax.lax.scan, unroll=1)
 
             def one(frees, xfer, arrivals, idx):
                 # convert the cycle constants INSIDE the traced function:
-                # tracing happens under enable_x64(), so the float64 values
-                # survive (a module-level jnp.asarray would downcast to f32
-                # and quietly break bit-identity for non-f32-exact cycles)
+                # tracing happens under precision.x64(), so the 64-bit values
+                # survive (a module-level jnp.asarray would downcast to 32
+                # bits and quietly break bit-identity)
                 stages = tuple(
                     (jnp.asarray(c), jnp.asarray(m)) for c, m in np_stages
                 )
                 return run_fabric_kernel(
                     jnp, jax.lax.scan, stages, frees, arrivals, idx,
-                    concurrency, percentiles, job_scan=job_scan, xfer=xfer,
+                    concurrency, job_scan=job_scan, xfer=xfer,
                     collect_stats=collect, window=window,
                     return_state=return_state,
                 )
@@ -754,37 +759,42 @@ class VirtualTimeFabric:
             )
         for g in self._groups(allocs, placements):
             if engine == "jax":
-                from jax.experimental import enable_x64
+                from ..core.precision import x64
 
                 fn = self._jax_runner(
-                    g, concurrency, n, tuple(percentiles),
-                    collect=collect_stats, window=window,
+                    g, concurrency, n, collect=collect_stats, window=window,
                 )
-                with enable_x64():
-                    out = fn(g.frees, g.xfer, times[g.rows], tuple(idx))
-                t_arr, comp, pct = (np.asarray(o) for o in out[:3])
+                with x64():
+                    out = fn(
+                        tuple(to_bits(f) for f in g.frees),
+                        None if g.xfer is None else to_bits(g.xfer),
+                        to_bits(times[g.rows]),
+                        tuple(idx),
+                    )
+                t_arr, comp = from_bits(out[0]), from_bits(out[1])
                 if collect_stats:
-                    busy[g.rows] = np.asarray(out[3])
-                    wait[g.rows] = np.asarray(out[4])
+                    busy[g.rows] = np.asarray(out[2])
+                    wait[g.rows] = np.asarray(out[3])
             else:
                 t_arr = np.zeros((len(g.rows), n))
                 comp = np.zeros((len(g.rows), n))
-                pct = np.zeros((len(g.rows), len(percentiles)))
                 for k, row in enumerate(g.rows):
                     frees = tuple(f[k].copy() for f in g.frees)
                     out = run_fabric_kernel(
                         np, _np_scan, g.stages, frees, times[row],
-                        tuple(idx), concurrency, tuple(percentiles),
+                        tuple(idx), concurrency,
                         xfer=None if g.xfer is None else g.xfer[k],
                         collect_stats=collect_stats, window=window,
                     )
-                    t_arr[k], comp[k], pct[k] = out[:3]
+                    t_arr[k], comp[k] = out[:2]
                     if collect_stats:
-                        busy[row] = np.asarray(out[3])
-                        wait[row] = np.asarray(out[4])
+                        busy[row] = np.asarray(out[2])
+                        wait[row] = np.asarray(out[3])
             arrivals[g.rows] = t_arr
             completions[g.rows] = comp
-            pcts[g.rows] = pct
+            pcts[g.rows] = [
+                percentile_kernel(np, c - t, percentiles) for t, c in zip(t_arr, comp)
+            ]
         return VTResult(
             arrivals, completions, pcts, tuple(percentiles), self.clock_hz,
             layer_busy=busy, layer_wait=wait,

@@ -11,8 +11,10 @@ row axis (VPU-friendly: the reduced axis is the 128-wide lane dimension for
 the default 128-row block), and folds the ceil-div read count on the fly.
 
 Outputs are laid out block-major — ``ones`` as (B, planes, S) and ``cycles``
-as (B, S), last dimension S — so writes stay lane-contiguous; the host-side
-wrapper transposes back to the profiler's (S, B) convention.  Like
+as (B, 1, S), last dimension S — so writes stay lane-contiguous and every
+block's last two dimensions span the whole array, as the TPU's (8, 128)
+tiling rule asks; the host-side wrapper transposes back to the profiler's
+(S, B) convention.  Like
 ``zskip_matmul``, the kernel runs under ``interpret=True`` off-TPU (CI
 exercises exactly that path).
 """
@@ -38,7 +40,7 @@ def bitplane_profile_kernel(
     q_ref, ones_ref, cyc_ref, *, input_bits: int, rows_per_read: int, cycles_per_read: int
 ):
     """One block: (1, S, r) int32 quantized patches -> per-plane popcounts
-    (1, planes, S) and zskip cycles (1, S)."""
+    (1, planes, S) and zskip cycles (1, 1, S)."""
     q = q_ref[0]  # (S, r)
     total = jnp.zeros((q.shape[0],), jnp.int32)
     for p in range(input_bits):
@@ -46,7 +48,7 @@ def bitplane_profile_kernel(
         ones = jnp.sum((q >> (input_bits - 1 - p)) & 1, axis=1, dtype=jnp.int32)
         ones_ref[0, p, :] = ones
         total += jnp.maximum(1, (ones + rows_per_read - 1) // rows_per_read)
-    cyc_ref[0, :] = cycles_per_read * total
+    cyc_ref[0, 0, :] = cycles_per_read * total
 
 
 @functools.partial(
@@ -74,20 +76,21 @@ def bitplane_block_profile(
         rows_per_read=rows_per_read,
         cycles_per_read=cycles_per_read,
     )
-    return pl.pallas_call(
+    ones, cyc = pl.pallas_call(
         kernel,
         grid=(b,),
         in_specs=[pl.BlockSpec((1, s, r), lambda i: (i, 0, 0))],
         out_specs=[
             pl.BlockSpec((1, input_bits, s), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, s), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, s), lambda i: (i, 0, 0)),
         ],
         out_shape=(
             jax.ShapeDtypeStruct((b, input_bits, s), jnp.int32),
-            jax.ShapeDtypeStruct((b, s), jnp.int32),
+            jax.ShapeDtypeStruct((b, 1, s), jnp.int32),
         ),
         interpret=interpret,
     )(q_blocks)
+    return ones, cyc[:, 0, :]
 
 
 def bitplane_cycle_bank(
@@ -96,8 +99,6 @@ def bitplane_cycle_bank(
     *,
     input_bits: int = 8,
     cycles_per_read: int = 8,
-    use_pallas: bool = False,
-    interpret: bool = False,
 ) -> jax.Array:
     """TRACEABLE multi-ADC zero-skip costing: one popcount, A re-costings.
 
@@ -105,36 +106,19 @@ def bitplane_cycle_bank(
     bit-plane ONCE (shift-and-mask, the same integers as ``np.unpackbits``
     or the Pallas kernel) and re-costs them for every ADC precision in
     ``rows_per_read`` — the whole ADC axis of a sweep from a single shared
-    capture, with no host round-trip.  Returns float64-able int32 cycles
-    shaped ``(A, ..., S)``; padded (all-zero) blocks cost the 1-read floor
-    per plane and must be masked by the caller, exactly like the profiler's
+    capture, with no host round-trip.  Returns int32 cycles shaped
+    ``(A, ..., S)``; padded (all-zero) blocks cost the 1-read floor per
+    plane and must be masked by the caller, exactly like the profiler's
     short last block.
-
-    ``use_pallas=True`` routes the popcount through ``bitplane_block_profile``
-    (TPU path; ``interpret=True`` off-TPU) — ones are bit-identical either
-    way, so the jnp path is the default inside large fused programs where a
-    grid launch per layer buys nothing on CPU.
     """
-    if use_pallas:
-        if q_blocks.ndim != 3:
-            raise ValueError(f"pallas path needs (B, S, r), got {q_blocks.shape}")
-        ones, _ = bitplane_block_profile(
-            q_blocks.astype(jnp.int32),
-            input_bits=input_bits,
-            rows_per_read=int(rows_per_read[0]),
-            cycles_per_read=cycles_per_read,
-            interpret=interpret,
-        )
-        ones = jnp.moveaxis(ones, 1, -1)  # (B, S, planes)
-    else:
-        q = q_blocks.astype(jnp.int32)
-        ones = jnp.stack(
-            [
-                ((q >> (input_bits - 1 - p)) & 1).sum(axis=-1, dtype=jnp.int32)
-                for p in range(input_bits)
-            ],
-            axis=-1,
-        )  # (..., S, planes), plane 0 = MSB
+    q = q_blocks.astype(jnp.int32)
+    ones = jnp.stack(
+        [
+            ((q >> (input_bits - 1 - p)) & 1).sum(axis=-1, dtype=jnp.int32)
+            for p in range(input_bits)
+        ],
+        axis=-1,
+    )  # (..., S, planes), plane 0 = MSB
     banks = [
         cycles_per_read
         * jnp.maximum(1, (ones + rpr - 1) // rpr).sum(axis=-1, dtype=jnp.int32)

@@ -27,9 +27,12 @@ each unit to its (layer, block) cell); proportional configs ride along
 with ``budget = 0`` and their host-precomputed replicas as the warm start
 — budget 0 makes the greedy a no-op, so one kernel serves every family.
 
-Off-TPU the kernel runs ``interpret=True`` (float64, CI exercises exactly
-that path); on TPU the natural dtype is float32 — callers that need the
-1e-12 contract should stay on the XLA path there.
+The kernel runs in interpret mode only (float64, off-TPU).  It does not
+compile for a TPU: Mosaic has no 64-bit types, so float64 cannot lower,
+and in float32 the in-kernel gathers (``base[aidx]``, ``[sel]``) are
+refused — and float32 would break the 1e-12 contract anyway.  On a TPU
+``fused_alloc_eval`` raises ``PALLAS_ON_TPU`` instead; the XLA path
+(``dse.fused`` ``engine="xla"``) is the one that runs there.
 """
 
 from __future__ import annotations
@@ -41,8 +44,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..core.alloc.greedy import greedy_batch_kernel
+from ..core.device import interpret_mode
 
-__all__ = ["fused_alloc_eval", "fused_alloc_eval_kernel"]
+__all__ = ["PALLAS_ON_TPU", "fused_alloc_eval", "fused_alloc_eval_kernel"]
+
+PALLAS_ON_TPU = (
+    "engine='pallas' (kernels.fused_alloc_eval) cannot run on a TPU: the "
+    "kernel's float64 contract does not lower to Mosaic, which has no 64-bit "
+    "types, and in float32 its in-kernel gathers are refused; use "
+    "engine='xla', which runs the same allocate+eval on the TPU in float64"
+)
 
 
 def fused_alloc_eval_kernel(
@@ -138,18 +149,17 @@ def fused_alloc_eval(
     n_images: int = 64,
     clock_hz: float = 1e9,
     block_configs: int = 128,
-    interpret: bool | None = None,
 ):
     """Run C configs through the fused allocate+eval kernel.
 
     Returns ``(T, ips, layer_T, util, r, rem)`` with shapes ``(C,)/(C,)/
     (C, L)/(C, L)/(C, N)/(C,)``.  The config axis is padded to a multiple
     of ``block_configs`` by repeating config 0 (one compiled program per
-    shape) and truncated on return.  ``interpret=None`` auto-selects
-    interpret mode off-TPU.
+    shape) and truncated on return.  Runs in interpret mode; on a TPU it
+    raises ``PALLAS_ON_TPU``.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    if not interpret_mode():
+        raise NotImplementedError(PALLAS_ON_TPU)
     mean_b, max_b, pm_mean, pm_max, busy = (jnp.asarray(x) for x in banks)
     base = jnp.asarray(base)
     cost = jnp.atleast_2d(jnp.asarray(cost))  # (1, N)
@@ -220,7 +230,7 @@ def fused_alloc_eval(
             jax.ShapeDtypeStruct((fullc, n), f),
             jax.ShapeDtypeStruct((fullc,), f),
         ),
-        interpret=interpret,
+        interpret=True,
     )(
         base.astype(f),
         cost.astype(f),

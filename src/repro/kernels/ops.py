@@ -1,30 +1,23 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On this container (CPU) the kernels run with interpret=True; on a real TPU
-set ``REPRO_PALLAS_INTERPRET=0`` (or rely on the default platform check).
+Interpret mode follows the backend (``core.device.interpret_mode``): on
+when no TPU backs jax, off on a TPU.
 """
 
 from __future__ import annotations
 
-import os
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 
+from ..core.device import interpret_mode
 from .flash_attention import flash_attention as _flash
 from .ssd_scan import ssd_chunk as _ssd_chunk
 from .zskip_matmul import zskip_matmul as _zskip
 from .ref import block_mask_ref
 
-__all__ = ["interpret_mode", "zskip_matmul_op", "flash_attention_op", "ssd_chunk_op"]
-
-
-def interpret_mode() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false")
-    return jax.default_backend() != "tpu"
+__all__ = ["zskip_matmul_op", "flash_attention_op", "ssd_chunk_op"]
 
 
 @partial(jax.jit, static_argnames=("bm", "bn", "bk"))

@@ -94,7 +94,7 @@ def test_event_schedule_matches_batch_kernel(problem):
 @given(_problem(max_units=5), st.integers(0, 2 ** 31 - 1))
 @settings(max_examples=10, deadline=None)
 def test_fused_kernel_greedy_matches_batch(problem, seed):
-    from jax.experimental import enable_x64
+    from repro.core.precision import x64
 
     from repro.kernels.fused_alloc_eval import fused_alloc_eval
 
@@ -118,13 +118,13 @@ def test_fused_kernel_greedy_matches_batch(problem, seed):
     )
     a_idx = rng.integers(0, a, size=c).astype(np.int32)
     r0_b = np.ones((c, n)) if r0 is None else np.broadcast_to(r0, (c, n)).copy()
-    with enable_x64():
+    with x64():
         *_, r, rem = fused_alloc_eval(
             bases, cost, umap, banks, np.ones((l, b), bool),
             np.ones(l), np.ones(l), np.ones(l),
             budgets, a_idx, a_idx.copy(),
             rng.integers(0, 2, size=c).astype(bool), r0_b,
-            block_configs=max(1, c // 2), interpret=True,
+            block_configs=max(1, c // 2),
         )
     want = greedy_allocate_batch(base, cost, budgets, initial_replicas=r0_b)
     np.testing.assert_array_equal(np.asarray(r), want.replicas)

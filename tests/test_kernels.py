@@ -126,19 +126,18 @@ def test_fused_alloc_eval_matches_oracles(block_configs):
     (same kernel body — warm starts, ties, budget 0 included) and eval
     columns equal to the scalar ``_eval_kernel`` per config.  The block
     grid pads by repeating config 0; every tiling must agree."""
-    from jax.experimental import enable_x64
+    from repro.core.precision import x64
 
     from repro.core.alloc.greedy import greedy_allocate_batch
     from repro.core.cim.simulate import _eval_kernel
 
     (base, cost, umap, banks, b_mask, ppi, width, larr,
      budgets, a_idx, sel, lw, r0) = _fused_problem()
-    with enable_x64():
+    with x64():
         T, ips, layer_T, util, r, rem = fused_alloc_eval(
             base, cost, umap, banks, b_mask, ppi, width, larr,
             budgets, a_idx, sel, lw, r0,
             n_images=16, clock_hz=1e9, block_configs=block_configs,
-            interpret=True,
         )
     want = greedy_allocate_batch(
         base[a_idx], cost, budgets, initial_replicas=r0
@@ -160,15 +159,15 @@ def test_fused_alloc_eval_matches_oracles(block_configs):
 def test_fused_alloc_eval_budget_zero_is_warm_start_identity():
     """Budget 0 must return the warm start untouched — the contract that
     lets proportional configs ride through the greedy kernel as no-ops."""
-    from jax.experimental import enable_x64
+    from repro.core.precision import x64
 
     (base, cost, umap, banks, b_mask, ppi, width, larr,
      budgets, a_idx, sel, lw, r0) = _fused_problem(seed=1)
     budgets[:] = 0.0
-    with enable_x64():
+    with x64():
         *_, r, rem = fused_alloc_eval(
             base, cost, umap, banks, b_mask, ppi, width, larr,
-            budgets, a_idx, sel, lw, r0, interpret=True,
+            budgets, a_idx, sel, lw, r0,
         )
     np.testing.assert_array_equal(np.asarray(r), r0)
     np.testing.assert_array_equal(np.asarray(rem), np.zeros_like(budgets))

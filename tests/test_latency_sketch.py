@@ -16,6 +16,7 @@ Contracts:
 import numpy as np
 import pytest
 
+from repro.core.precision import x64
 from repro.fabric.metrics import (
     LatencySketch,
     SketchConfig,
@@ -91,7 +92,7 @@ def test_jit_scan_fold_bit_identical_to_numpy():
 
     rng = np.random.default_rng(4)
     lat = rng.lognormal(9, 1.2, 257)
-    with jax.experimental.enable_x64():
+    with x64():
         bnp = sketch_bucket(np, lat, CFG)
         bjx = np.asarray(sketch_bucket(jnp, jnp.asarray(lat), CFG))
         np.testing.assert_array_equal(bnp, bjx)

@@ -15,19 +15,19 @@ QS = (50.0, 95.0, 99.0)
 
 def _jnp():
     jax = pytest.importorskip("jax")
-    from jax.experimental import enable_x64
+    from repro.core.precision import x64
 
-    return jax, enable_x64
+    return jax, x64
 
 
 def test_single_request_scalar_equals_batch():
     lat = np.asarray([1234.5])
     ref = percentile_kernel(np, lat, QS)
     np.testing.assert_array_equal(ref, [1234.5] * 3)
-    jax, enable_x64 = _jnp()
+    jax, x64 = _jnp()
     import jax.numpy as jnp
 
-    with enable_x64():
+    with x64():
         out = np.asarray(jax.jit(lambda x: percentile_kernel(jnp, x, QS))(lat))
     np.testing.assert_array_equal(out, ref)
 
@@ -36,10 +36,10 @@ def test_all_ties_scalar_equals_batch():
     lat = np.full(37, 42.0)
     ref = percentile_kernel(np, lat, QS)
     np.testing.assert_array_equal(ref, [42.0] * 3)
-    jax, enable_x64 = _jnp()
+    jax, x64 = _jnp()
     import jax.numpy as jnp
 
-    with enable_x64():
+    with x64():
         out = np.asarray(jax.jit(lambda x: percentile_kernel(jnp, x, QS))(lat))
     np.testing.assert_array_equal(out, ref)
 
@@ -49,10 +49,10 @@ def test_general_batch_matches_scalar_bitwise():
     lat = rng.exponential(100.0, size=501)
     ref = percentile_kernel(np, lat, QS)
     np.testing.assert_array_equal(ref, np.percentile(lat, [50, 95, 99]))
-    jax, enable_x64 = _jnp()
+    jax, x64 = _jnp()
     import jax.numpy as jnp
 
-    with enable_x64():
+    with x64():
         out = np.asarray(jax.jit(lambda x: percentile_kernel(jnp, x, QS))(lat))
     np.testing.assert_allclose(out, ref, rtol=1e-12)
 

@@ -16,6 +16,7 @@ from hypothesis import given, settings
 from hypothesis import strategies as st
 from hypothesis.extra import numpy as hnp
 
+from repro.core.precision import x64
 from repro.fabric.metrics import percentile_kernel
 
 _floats = st.floats(
@@ -65,7 +66,7 @@ def test_jax_path_matches_numpy_reference():
     rng = np.random.default_rng(0)
     lat = rng.gamma(2.0, 1e4, size=257)
     qs = (0.0, 12.5, 50.0, 95.0, 99.0, 100.0)
-    with jax.experimental.enable_x64():
+    with x64():
         got = np.asarray(
             jax.jit(lambda x: percentile_kernel(jnp, x, qs))(jnp.asarray(lat))
         )

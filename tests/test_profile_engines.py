@@ -201,7 +201,7 @@ def test_streaming_batches_cover_every_sample():
     so an ownership-mask or rowbits-accumulation bug cannot hide."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from repro.core.precision import x64
 
     from repro.core.cim import profile as P
 
@@ -228,7 +228,7 @@ def test_streaming_batches_cover_every_sample():
             jnp.arange(batch * l.patches_per_image, dtype=jnp.int32)
             for l in spec.layers
         )
-        with enable_x64():
+        with x64():
             rb, q_full = P._capture_jit(spec, weights, sel_full, x[i0 : i0 + batch])
         for li, layer in enumerate(spec.layers):
             rowbits[li] += np.asarray(rb[li])

@@ -17,6 +17,7 @@ from hypothesis import given, settings
 from hypothesis import strategies as st
 from hypothesis.extra import numpy as hnp
 
+from repro.core.precision import x64
 from repro.fabric.metrics import (
     LatencySketch,
     SketchConfig,
@@ -116,7 +117,7 @@ def test_jit_fold_matches_numpy_on_representative_population():
     def step(s, v):
         return sketch_update(jnp, s, v, CFG), None
 
-    with jax.experimental.enable_x64():
+    with x64():
         out, _ = jax.jit(lambda s, x: jax.lax.scan(step, s, x))(
             tuple(jnp.asarray(a) for a in sketch_init(jnp, CFG)), jnp.asarray(lat)
         )
