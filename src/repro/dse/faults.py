@@ -243,7 +243,6 @@ def run_fault_sweep(
         killed[i] = plan.n_killed
         repaired[i] = plan.n_repaired
         stalls[i] = plan.total_stall_cycles
-        tel.gauge("dse.faults.points_done", i + 1)
 
     return FaultSweepResult(
         points=list(points),

@@ -86,6 +86,7 @@ from ..core.cim.simulate import (
     images_per_sec,
 )
 from ..core.cim.topology import allocate_placed, stage_transfer_matrix
+from ..fabric.telemetry import get_telemetry
 from .sweep import (
     ChipSweepPoint,
     FabricEval,
@@ -478,63 +479,64 @@ class FusedPipeline:
         """
         from ..core.precision import x64
 
-        from ..fabric.telemetry import get_telemetry
-
         _check_engine(engine)
-        policies, n_pes, total = self._validate(policies, n_pes)
-        a_idx = np.broadcast_to(
-            np.atleast_1d(np.asarray(a_idx, dtype=np.int32)), policies.shape
-        ).copy()
-        if a_idx.size and (a_idx.min() < 0 or a_idx.max() >= len(self.adc_bits)):
-            raise ValueError(
-                f"a_idx out of range for {len(self.adc_bits)} ADC variants"
-            )
-        C = policies.shape[0]
-        budgets = (total - self.base_arrays).astype(np.float64)
-        kind = np.array([_KIND[p] for p in policies], dtype=np.int32)
-        zskip = policies != "baseline"
-        layerwise = np.isin(policies, _LAYERWISE_FLOW)
-        A = len(self.variants)
-        sel = (a_idx + np.where(zskip, A, 0)).astype(np.int32)
-
-        # ---- stage 2, host side: every replica vector from shared tables.
-        # Proportional replicas are MACs-only config constants (the staged
-        # largest-remainder routine, exact); the greedy families replay the
-        # per-variant event schedules — element-wise identical to the
-        # lock-step kernel, at a searchsorted per config instead of a
-        # bisection + residual loop over (C, N) tensors per chunk.
-        r_layer = np.ones((C, self.L))  # rows of family "L" only
-        prop = kind == 0
-        if prop.any():
-            res = proportional_allocate_batch(
-                self.macs, self.layer_arrays, budgets[prop]
-            )
-            r_layer[prop] = res.replicas.astype(np.float64)
-        if engine == "pallas":
-            return self._pallas_eval(
-                sel, a_idx, kind, budgets, layerwise, zskip, r_layer, total,
-                int(n_images), float(clock_hz), int(chunk), need_dups,
-                return_bank,
-            )
-        used_f = np.zeros(C)
-        rows_B = np.nonzero(kind == 2)[0]
-        r_blk = np.ones((rows_B.size, self.N))  # family "B", rows_B order
-        for k, rows_k in ((1, np.nonzero(kind == 1)[0]), (2, rows_B)):
-            if rows_k.size == 0:
-                continue
-            bmax = float(budgets[rows_k].max())
-            for a in np.unique(a_idx[rows_k]):
-                rk = a_idx[rows_k] == a
-                got = self._schedule(k, int(a), bmax).replicas_at(
-                    budgets[rows_k[rk]]
+        tel = get_telemetry()
+        with tel.timed("dse.fused.allocate"):
+            policies, n_pes, total = self._validate(policies, n_pes)
+            a_idx = np.broadcast_to(
+                np.atleast_1d(np.asarray(a_idx, dtype=np.int32)), policies.shape
+            ).copy()
+            if a_idx.size and (a_idx.min() < 0 or a_idx.max() >= len(self.adc_bits)):
+                raise ValueError(
+                    f"a_idx out of range for {len(self.adc_bits)} ADC variants"
                 )
-                if k == 1:
-                    r_layer[rows_k[rk]] = got.replicas.astype(np.float64)
-                else:
-                    r_blk[rk] = got.replicas.astype(np.float64)
-        rows_L = np.nonzero(kind != 2)[0]
-        used_f[rows_L] = (r_layer[rows_L] - 1.0) @ self.layer_arrays
-        used_f[rows_B] = ((r_blk - 1.0) * self.cost_blk).sum(axis=1)
+            C = policies.shape[0]
+            budgets = (total - self.base_arrays).astype(np.float64)
+            kind = np.array([_KIND[p] for p in policies], dtype=np.int32)
+            zskip = policies != "baseline"
+            layerwise = np.isin(policies, _LAYERWISE_FLOW)
+            A = len(self.variants)
+            sel = (a_idx + np.where(zskip, A, 0)).astype(np.int32)
+
+            # ---- stage 2, host side: every replica vector from shared
+            # tables.  Proportional replicas are MACs-only config constants
+            # (the staged largest-remainder routine, exact); the greedy
+            # families replay the per-variant event schedules — element-wise
+            # identical to the lock-step kernel, at a searchsorted per config
+            # instead of a bisection + residual loop over (C, N) tensors per
+            # chunk.
+            r_layer = np.ones((C, self.L))  # rows of family "L" only
+            prop = kind == 0
+            if prop.any():
+                res = proportional_allocate_batch(
+                    self.macs, self.layer_arrays, budgets[prop]
+                )
+                r_layer[prop] = res.replicas.astype(np.float64)
+            if engine == "pallas":
+                return self._pallas_eval(
+                    sel, a_idx, kind, budgets, layerwise, zskip, r_layer, total,
+                    int(n_images), float(clock_hz), int(chunk), need_dups,
+                    return_bank,
+                )
+            used_f = np.zeros(C)
+            rows_B = np.nonzero(kind == 2)[0]
+            r_blk = np.ones((rows_B.size, self.N))  # family "B", rows_B order
+            for k, rows_k in ((1, np.nonzero(kind == 1)[0]), (2, rows_B)):
+                if rows_k.size == 0:
+                    continue
+                bmax = float(budgets[rows_k].max())
+                for a in np.unique(a_idx[rows_k]):
+                    rk = a_idx[rows_k] == a
+                    got = self._schedule(k, int(a), bmax).replicas_at(
+                        budgets[rows_k[rk]]
+                    )
+                    if k == 1:
+                        r_layer[rows_k[rk]] = got.replicas.astype(np.float64)
+                    else:
+                        r_blk[rk] = got.replicas.astype(np.float64)
+            rows_L = np.nonzero(kind != 2)[0]
+            used_f[rows_L] = (r_layer[rows_L] - 1.0) @ self.layer_arrays
+            used_f[rows_B] = ((r_blk - 1.0) * self.cost_blk).sum(axis=1)
 
         outs = {
             "total_cycles": np.zeros(C),
@@ -543,7 +545,6 @@ class FusedPipeline:
         }
         if need_dups:
             outs["dups_lb"] = np.zeros((C, self.L, self.B))
-        tel = get_telemetry()
         csize_max = n_chunks = 0
         with x64():
             for fam, rows, r_fam in (("L", rows_L, r_layer), ("B", rows_B, r_blk)):
@@ -554,30 +555,33 @@ class FusedPipeline:
                 csize_max = max(csize_max, csize)
                 for j0 in range(0, rows.size, csize):
                     part = rows[j0 : j0 + csize]
-                    pad = csize - part.size
-                    take = (
-                        part
-                        if pad == 0
-                        else np.concatenate([part, np.repeat(part[:1], pad)])
-                    )  # pad repeating row 0: one compilation per partition
-                    # family "L" replicas index by global row; family "B" by
-                    # position (r_blk rows are laid out in rows_B order)
-                    if fam == "L":
-                        r_take = r_fam[take]
-                    else:
-                        r_take = r_fam[j0 : j0 + csize]
-                        if pad:
-                            r_take = np.concatenate(
-                                [r_take, np.repeat(r_take[:1], pad, axis=0)]
-                            )
-                    T, layer_T, util, dups = fn(
-                        sel[take], layerwise[take], r_take
-                    )
-                    outs["total_cycles"][part] = np.asarray(T)[: part.size]
-                    outs["layer_cycles"][part] = np.asarray(layer_T)[: part.size]
-                    outs["layer_utilization"][part] = np.asarray(util)[: part.size]
-                    if need_dups:
-                        outs["dups_lb"][part] = np.asarray(dups)[: part.size]
+                    with tel.timed("dse.fused.dispatch", configs=part.size):
+                        pad = csize - part.size
+                        take = (
+                            part
+                            if pad == 0
+                            else np.concatenate([part, np.repeat(part[:1], pad)])
+                        )  # pad repeating row 0: one compilation per partition
+                        # family "L" replicas index by global row; family "B"
+                        # by position (r_blk rows are laid out in rows_B order)
+                        if fam == "L":
+                            r_take = r_fam[take]
+                        else:
+                            r_take = r_fam[j0 : j0 + csize]
+                            if pad:
+                                r_take = np.concatenate(
+                                    [r_take, np.repeat(r_take[:1], pad, axis=0)]
+                                )
+                        T, layer_T, util, dups = fn(
+                            sel[take], layerwise[take], r_take
+                        )
+                    with tel.timed("dse.fused.fetch", configs=part.size):
+                        n = part.size
+                        outs["total_cycles"][part] = np.asarray(T)[:n]
+                        outs["layer_cycles"][part] = np.asarray(layer_T)[:n]
+                        outs["layer_utilization"][part] = np.asarray(util)[:n]
+                        if need_dups:
+                            outs["dups_lb"][part] = np.asarray(dups)[:n]
                     n_chunks += 1
         outs["images_per_sec"] = images_per_sec(
             outs["total_cycles"], n_images, clock_hz
@@ -932,54 +936,59 @@ def run_fused_sweep(
     total = np.zeros(C, dtype=np.int64)
     pcts = np.full((C, 3), np.nan) if fabric is not None else None
 
-    groups: dict[tuple, list[int]] = {}
-    for i, p in enumerate(points):
-        groups.setdefault((p.network, _canonical(p.array)), []).append(i)
+    tel = get_telemetry()
+    with tel.timed("dse.fused.sweep", configs=C):
+        with tel.timed("dse.fused.group", configs=C):
+            groups: dict[tuple, list[int]] = {}
+            for i, p in enumerate(points):
+                groups.setdefault((p.network, _canonical(p.array)), []).append(i)
 
-    elapsed = 0.0
-    for (net, arr), rows in groups.items():
-        adcs = tuple(sorted({points[i].array.adc_bits for i in rows}))
-        pipe = get_fused_pipeline(
-            net,
-            arr,
-            adcs,
-            profile_images=profile_images,
-            sample_patches=sample_patches,
-            seed=seed,
-            arrays_per_pe=arrays_per_pe,
-            shard=shard_devices,
-        )
-        idx = np.asarray(rows)
-        a_idx = np.array(
-            [adcs.index(points[i].array.adc_bits) for i in rows], dtype=np.int32
-        )
-        pols = np.array([points[i].policy for i in rows], dtype=object)
-        pes = np.array([points[i].n_pes for i in rows], dtype=np.int64)
-        t0 = time.perf_counter()
-        res = pipe(
-            a_idx, pols, pes, n_images=n_images, chunk=chunk,
-            need_dups=fabric is not None, engine=engine,
-        )
-        out["total_cycles"][idx] = res["total_cycles"]
-        out["images_per_sec"][idx] = res["images_per_sec"]
-        out["mean_utilization"][idx] = res["layer_utilization"].mean(axis=1)
-        used[idx] = res["arrays_used"]
-        total[idx] = res["arrays_total"]
-        if fabric is not None:
-            gaps = np.random.default_rng(fabric.seed).exponential(
-                1.0, size=fabric.n_requests
+        elapsed = 0.0
+        for (net, arr), rows in groups.items():
+            with tel.timed("dse.fused.group", configs=len(rows)):
+                adcs = tuple(sorted({points[i].array.adc_bits for i in rows}))
+                idx = np.asarray(rows)
+                a_idx = np.array(
+                    [adcs.index(points[i].array.adc_bits) for i in rows],
+                    dtype=np.int32,
+                )
+                pols = np.array([points[i].policy for i in rows], dtype=object)
+                pes = np.array([points[i].n_pes for i in rows], dtype=np.int64)
+            pipe = get_fused_pipeline(
+                net,
+                arr,
+                adcs,
+                profile_images=profile_images,
+                sample_patches=sample_patches,
+                seed=seed,
+                arrays_per_pe=arrays_per_pe,
+                shard=shard_devices,
             )
-            rates = fabric.load_frac * res["images_per_sec"] / CLOCK_HZ
-            times = np.cumsum(gaps)[None, :] / rates[:, None]
-            pcts[idx] = pipe.fabric_percentiles(
-                a_idx,
-                res["dups_lb"],
-                res["layerwise"],
-                res["zskip"],
-                times,
-                seed=fabric.seed,
+            t0 = time.perf_counter()
+            res = pipe(
+                a_idx, pols, pes, n_images=n_images, chunk=chunk,
+                need_dups=fabric is not None, engine=engine,
             )
-        elapsed += time.perf_counter() - t0
+            out["total_cycles"][idx] = res["total_cycles"]
+            out["images_per_sec"][idx] = res["images_per_sec"]
+            out["mean_utilization"][idx] = res["layer_utilization"].mean(axis=1)
+            used[idx] = res["arrays_used"]
+            total[idx] = res["arrays_total"]
+            if fabric is not None:
+                gaps = np.random.default_rng(fabric.seed).exponential(
+                    1.0, size=fabric.n_requests
+                )
+                rates = fabric.load_frac * res["images_per_sec"] / CLOCK_HZ
+                times = np.cumsum(gaps)[None, :] / rates[:, None]
+                pcts[idx] = pipe.fabric_percentiles(
+                    a_idx,
+                    res["dups_lb"],
+                    res["layerwise"],
+                    res["zskip"],
+                    times,
+                    seed=fabric.seed,
+                )
+            elapsed += time.perf_counter() - t0
 
     return SweepResult(
         points=list(points),

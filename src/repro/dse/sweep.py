@@ -297,7 +297,6 @@ def run_sweep(
     tel = get_telemetry()
     tel.gauge("dse.sweep.points", C)
     tel.gauge("dse.sweep.groups", len(groups))
-    done = 0
     for (net, arr), rows in groups.items():
         spec, prof = get_profiled(net, arr, **prof_kw)
         idx = np.asarray(rows)
@@ -353,8 +352,6 @@ def run_sweep(
             )
         elapsed += time.perf_counter() - t0
         group_timer.__exit__(None, None, None)
-        done += len(rows)
-        tel.gauge("dse.sweep.points_done", done)
 
     return SweepResult(
         points=list(points),

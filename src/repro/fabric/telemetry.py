@@ -1,9 +1,10 @@
 """Telemetry recorder: counters / gauges / histograms / spans.
 
 The observability spine of the fabric stack.  Producers (the event engine's
-pool stats, the DSE caches, the benchmark harness) talk to ONE tiny
-interface — ``count`` / ``gauge`` / ``observe`` / ``span`` / ``timed`` — and
-consumers read a JSON-friendly ``snapshot()``.
+pool stats, the DSE caches, the fused sweep and replay hot paths, the
+benchmark harness) talk to ONE tiny interface — ``count`` / ``gauge`` /
+``observe`` / ``span`` / ``timed`` — and consumers read a JSON-friendly
+``snapshot()``.
 
 Zero overhead when off, by construction: the process-global recorder
 defaults to ``NULL_TELEMETRY``, whose methods are empty single-statement
@@ -14,10 +15,16 @@ is never executed at all, so instrumented builds are bit-identical AND
 cycle-identical to uninstrumented ones (pinned by the telemetry bench:
 ``BENCH_telemetry.json``).
 
-Wall-clock spans use ``time.perf_counter``; simulated-time spans (request
-stage residence in fabric cycles) are exported by ``repro.obs.trace`` from
-``FabricSim`` stats rather than recorded here — the recorder never injects
-host time into simulated time.
+Wall-clock spans use ``time.perf_counter``, and each ``timed()`` span of a
+live recorder is also a ``jax.profiler.TraceAnnotation`` of the same name:
+under a profiler trace the program's spans sit on the host thread's line,
+on the device events' clock, so each device idle gap can be named by the
+span that was open in it.  Every span records its ``parent``, the span
+that was open when it started, so a layer's self time (its duration less
+what its children cover) can be read from a snapshot.  Simulated-time spans
+(request stage residence in fabric cycles) are exported by
+``repro.obs.trace`` from ``FabricSim`` stats rather than recorded here —
+the recorder never injects host time into simulated time.
 """
 
 from __future__ import annotations
@@ -46,6 +53,7 @@ class Span:
     start: float
     end: float
     attrs: dict = field(default_factory=dict)
+    parent: int | None = None  # index in ``Telemetry.spans`` of the enclosing span
 
     @property
     def duration(self) -> float:
@@ -64,6 +72,7 @@ class Telemetry:
         self.gauges: dict[str, float] = {}
         self.histograms: dict[str, list] = {}
         self.spans: list[Span] = []
+        self._open: list[int] = []  # indices of the timed() spans now open
 
     # ------------------------------------------------------------- recording
     def count(self, name: str, value: float = 1.0) -> None:
@@ -83,18 +92,29 @@ class Telemetry:
         self.histograms.setdefault(name, []).append(float(value))
 
     def span(self, name: str, start: float, end: float, **attrs) -> None:
-        self.spans.append(Span(name, float(start), float(end), attrs))
+        parent = self._open[-1] if self._open else None
+        self.spans.append(Span(name, float(start), float(end), attrs, parent))
 
     @contextmanager
     def timed(self, name: str, **attrs):
-        """Record a wall-clock span (and an ``<name>.s`` histogram sample)."""
+        """Record a wall-clock span around the body, also entered as a
+        ``jax.profiler.TraceAnnotation`` (a no-op unless a trace runs).
+        The span takes its place in ``spans`` when it opens, so spans are
+        listed in the order they started and a parent before its children."""
+        from jax.profiler import TraceAnnotation
+
+        i = len(self.spans)
+        parent = self._open[-1] if self._open else None
         t0 = time.perf_counter()
+        self.spans.append(Span(name, t0, t0, attrs, parent))
+        self._open.append(i)
         try:
-            yield
+            with TraceAnnotation(name):
+                yield
         finally:
             t1 = time.perf_counter()
-            self.span(name, t0, t1, **attrs)
-            self.observe(f"{name}.s", t1 - t0)
+            self._open.pop()
+            self.spans[i] = Span(name, t0, t1, attrs, parent)
 
     # --------------------------------------------------------------- reading
     def hist_stats(self, name: str) -> dict:
@@ -117,7 +137,8 @@ class Telemetry:
             "gauges": dict(self.gauges),
             "histograms": {k: self.hist_stats(k) for k in self.histograms},
             "spans": [
-                {"name": s.name, "start": s.start, "end": s.end, **s.attrs}
+                {"name": s.name, "start": s.start, "end": s.end,
+                 "parent": s.parent, **s.attrs}
                 for s in self.spans
             ],
         }
@@ -127,6 +148,7 @@ class Telemetry:
         self.gauges.clear()
         self.histograms.clear()
         self.spans.clear()
+        self._open.clear()
 
 
 class _NullTelemetry(Telemetry):

@@ -48,6 +48,7 @@ from ..core.cim.simulate import Allocation, CLOCK_HZ, _layer_patch_cycles
 from ..core.precision import add, from_bits, inf, ninf, sub, to_bits, value
 from .arrivals import ArrivalProcess, ClosedLoop, PoissonOpen, arrival_times
 from .metrics import LatencyStats, latency_stats, percentile_kernel, steady_throughput
+from .telemetry import get_telemetry
 
 __all__ = [
     "CoarsenConfig",
@@ -711,94 +712,104 @@ class VirtualTimeFabric:
         if engine not in ("jax", "numpy"):
             raise ValueError(f"engine must be 'jax' or 'numpy', got {engine!r}")
         allocs = list(allocs)
-        if not allocs:
-            raise ValueError("need at least one allocation")
-        if placements is not None and len(placements) != len(allocs):
-            raise ValueError(
-                f"{len(placements)} placements for {len(allocs)} allocations"
-            )
-        procs = proc if isinstance(proc, list) else [proc] * len(allocs)
-        if len(procs) != len(allocs):
-            raise ValueError(f"{len(procs)} arrival processes for {len(allocs)} allocations")
-        closed = isinstance(procs[0], ClosedLoop)
-        if any(isinstance(p, ClosedLoop) != closed for p in procs):
-            raise ValueError("cannot mix closed- and open-loop processes in one batch")
-        if closed:
-            concurrency = procs[0].concurrency
-            if any(p.concurrency != concurrency or p.n_requests != procs[0].n_requests for p in procs):
-                raise ValueError("closed-loop batch needs identical (n_requests, concurrency)")
-            n = procs[0].n_requests
-            times = np.zeros((len(allocs), n))
-        else:
-            concurrency = None
-            tlist = [arrival_times(p) for p in procs]
-            n = tlist[0].size
-            if any(t.size != n for t in tlist):
-                raise ValueError("all arrival traces in a batch need the same length")
-            times = np.stack(tlist).astype(np.float64)
+        tel = get_telemetry()
+        with tel.timed("vt.batch", designs=len(allocs)):
+            if not allocs:
+                raise ValueError("need at least one allocation")
+            if placements is not None and len(placements) != len(allocs):
+                raise ValueError(
+                    f"{len(placements)} placements for {len(allocs)} allocations"
+                )
+            procs = proc if isinstance(proc, list) else [proc] * len(allocs)
+            if len(procs) != len(allocs):
+                raise ValueError(f"{len(procs)} arrival processes for {len(allocs)} allocations")
+            closed = isinstance(procs[0], ClosedLoop)
+            if any(isinstance(p, ClosedLoop) != closed for p in procs):
+                raise ValueError("cannot mix closed- and open-loop processes in one batch")
+            with tel.timed("vt.arrivals"):
+                if closed:
+                    concurrency = procs[0].concurrency
+                    if any(p.concurrency != concurrency or p.n_requests != procs[0].n_requests for p in procs):
+                        raise ValueError("closed-loop batch needs identical (n_requests, concurrency)")
+                    n = procs[0].n_requests
+                    times = np.zeros((len(allocs), n))
+                else:
+                    concurrency = None
+                    tlist = [arrival_times(p) for p in procs]
+                    n = tlist[0].size
+                    if any(t.size != n for t in tlist):
+                        raise ValueError("all arrival traces in a batch need the same length")
+                    times = np.stack(tlist).astype(np.float64)
 
-        # one draw shared by every group: sampling dims depend only on the
-        # profile (S_l, ppi_l), not on dataflow or zero-skipping
-        dims = [
-            (self._cyc[True][i].shape[0], l.patches_per_image)
-            for i, l in enumerate(self.spec.layers)
-        ]
-        idx = sample_service_indices(np.random.default_rng(seed), dims, n)
+            # one draw shared by every group: sampling dims depend only on the
+            # profile (S_l, ppi_l), not on dataflow or zero-skipping
+            dims = [
+                (self._cyc[True][i].shape[0], l.patches_per_image)
+                for i, l in enumerate(self.spec.layers)
+            ]
+            with tel.timed("vt.draws"):
+                idx = sample_service_indices(np.random.default_rng(seed), dims, n)
 
-        C = len(allocs)
-        L = len(self.spec.layers)
-        arrivals = np.zeros((C, n))
-        completions = np.zeros((C, n))
-        pcts = np.zeros((C, len(percentiles)))
-        busy = np.zeros((C, L)) if collect_stats else None
-        wait = np.zeros((C, L)) if collect_stats else None
-        if n == 0:
+            C = len(allocs)
+            L = len(self.spec.layers)
+            arrivals = np.zeros((C, n))
+            completions = np.zeros((C, n))
+            pcts = np.zeros((C, len(percentiles)))
+            busy = np.zeros((C, L)) if collect_stats else None
+            wait = np.zeros((C, L)) if collect_stats else None
+            if n == 0:
+                return VTResult(
+                    arrivals, completions, pcts, tuple(percentiles), self.clock_hz,
+                    layer_busy=busy, layer_wait=wait,
+                )
+            with tel.timed("vt.pack"):
+                groups = self._groups(allocs, placements)
+            for g in groups:
+                if engine == "jax":
+                    from ..core.precision import x64
+
+                    with tel.timed("vt.dispatch"):
+                        fn = self._jax_runner(
+                            g, concurrency, n, collect=collect_stats, window=window,
+                        )
+                        with x64():
+                            out = fn(
+                                tuple(to_bits(f) for f in g.frees),
+                                None if g.xfer is None else to_bits(g.xfer),
+                                to_bits(times[g.rows]),
+                                tuple(idx),
+                            )
+                    with tel.timed("vt.fetch"):
+                        t_arr, comp = from_bits(out[0]), from_bits(out[1])
+                        if collect_stats:
+                            busy[g.rows] = np.asarray(out[2])
+                            wait[g.rows] = np.asarray(out[3])
+                else:
+                    t_arr = np.zeros((len(g.rows), n))
+                    comp = np.zeros((len(g.rows), n))
+                    with tel.timed("vt.dispatch"):
+                        for k, row in enumerate(g.rows):
+                            frees = tuple(f[k].copy() for f in g.frees)
+                            out = run_fabric_kernel(
+                                np, _np_scan, g.stages, frees, times[row],
+                                tuple(idx), concurrency,
+                                xfer=None if g.xfer is None else g.xfer[k],
+                                collect_stats=collect_stats, window=window,
+                            )
+                            t_arr[k], comp[k] = out[:2]
+                            if collect_stats:
+                                busy[row] = np.asarray(out[2])
+                                wait[row] = np.asarray(out[3])
+                arrivals[g.rows] = t_arr
+                completions[g.rows] = comp
+                with tel.timed("vt.percentiles"):
+                    pcts[g.rows] = [
+                        percentile_kernel(np, c - t, percentiles) for t, c in zip(t_arr, comp)
+                    ]
             return VTResult(
                 arrivals, completions, pcts, tuple(percentiles), self.clock_hz,
                 layer_busy=busy, layer_wait=wait,
             )
-        for g in self._groups(allocs, placements):
-            if engine == "jax":
-                from ..core.precision import x64
-
-                fn = self._jax_runner(
-                    g, concurrency, n, collect=collect_stats, window=window,
-                )
-                with x64():
-                    out = fn(
-                        tuple(to_bits(f) for f in g.frees),
-                        None if g.xfer is None else to_bits(g.xfer),
-                        to_bits(times[g.rows]),
-                        tuple(idx),
-                    )
-                t_arr, comp = from_bits(out[0]), from_bits(out[1])
-                if collect_stats:
-                    busy[g.rows] = np.asarray(out[2])
-                    wait[g.rows] = np.asarray(out[3])
-            else:
-                t_arr = np.zeros((len(g.rows), n))
-                comp = np.zeros((len(g.rows), n))
-                for k, row in enumerate(g.rows):
-                    frees = tuple(f[k].copy() for f in g.frees)
-                    out = run_fabric_kernel(
-                        np, _np_scan, g.stages, frees, times[row],
-                        tuple(idx), concurrency,
-                        xfer=None if g.xfer is None else g.xfer[k],
-                        collect_stats=collect_stats, window=window,
-                    )
-                    t_arr[k], comp[k] = out[:2]
-                    if collect_stats:
-                        busy[row] = np.asarray(out[2])
-                        wait[row] = np.asarray(out[3])
-            arrivals[g.rows] = t_arr
-            completions[g.rows] = comp
-            pcts[g.rows] = [
-                percentile_kernel(np, c - t, percentiles) for t, c in zip(t_arr, comp)
-            ]
-        return VTResult(
-            arrivals, completions, pcts, tuple(percentiles), self.clock_hz,
-            layer_busy=busy, layer_wait=wait,
-        )
 
 
 # ------------------------------------------------- fabric-oracle refinement

@@ -53,7 +53,9 @@ def test_spans_and_timed():
     names = [s["name"] for s in snap["spans"]]
     assert names == ["load", "work"]
     assert snap["spans"][0]["layer"] == 2
-    assert "work.s" in snap["histograms"]  # timed() also feeds a histogram
+    work = snap["spans"][1]  # timed() records the span alone, no histogram twin
+    assert work["tag"] == "x" and work["end"] >= work["start"] and work["parent"] is None
+    assert "work.s" not in snap["histograms"]
     t.reset()
     assert t.snapshot() == {
         "counters": {},
