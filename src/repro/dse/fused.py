@@ -135,6 +135,52 @@ def _canonical(array: ArrayConfig) -> ArrayConfig:
     return array.variant(adc_bits=DEFAULT_ARRAY.adc_bits)
 
 
+def _group_points(points: list[SweepPoint]):
+    """Group sweep points by (network, rows-geometry) in one columnar pass.
+
+    Each distinct (network, array) pair, keyed by value, gets a small
+    integer code and is canonicalised once; pairs with equal canonical keys
+    share a group.  Returns ``(groups, a_idx, pols, pes, distinct)``:
+    ``groups`` lists ``(network, canonical array, adcs, rows)`` in order of
+    first appearance, ``adcs`` the sorted ADC widths of the group and
+    ``rows`` its point indices in ascending order; ``a_idx`` (each point's
+    index into its group's ``adcs``), ``pols`` and ``pes`` are per-point
+    columns; ``distinct`` counts the pairs canonicalised."""
+    C = len(points)
+    code_of: dict[tuple, int] = {}
+    codes = np.fromiter(
+        (code_of.setdefault((p.network, p.array), len(code_of)) for p in points),
+        np.int64,
+        C,
+    )
+    pols = np.array([p.policy for p in points], dtype=object)
+    pes = np.array([p.n_pes for p in points], dtype=np.int64)
+
+    pairs = list(code_of)
+    group_ids: dict[tuple, int] = {}
+    group_of = [
+        group_ids.setdefault((net, _canonical(arr)), len(group_ids))
+        for net, arr in pairs
+    ]
+    adc_sets: list[set] = [set() for _ in group_ids]
+    for (_, arr), g in zip(pairs, group_of):
+        adc_sets[g].add(arr.adc_bits)
+    adcs = [tuple(sorted(s)) for s in adc_sets]
+    a_of_code = np.array(
+        [adcs[g].index(arr.adc_bits) for (_, arr), g in zip(pairs, group_of)],
+        dtype=np.int32,
+    )
+    point_group = np.array(group_of, dtype=np.int64)[codes]
+    order = np.argsort(point_group, kind="stable")
+    counts = np.bincount(point_group, minlength=len(group_ids))
+    ends = np.cumsum(counts)
+    groups = [
+        (net, arr, adcs[g], order[ends[g] - counts[g] : ends[g]])
+        for (net, arr), g in group_ids.items()
+    ]
+    return groups, a_of_code[codes], pols, pes, len(pairs)
+
+
 class FusedPipeline:
     """Fused derive->allocate->eval for one (network, rows-geometry) group.
 
@@ -939,21 +985,13 @@ def run_fused_sweep(
     tel = get_telemetry()
     with tel.timed("dse.fused.sweep", configs=C):
         with tel.timed("dse.fused.group", configs=C):
-            groups: dict[tuple, list[int]] = {}
-            for i, p in enumerate(points):
-                groups.setdefault((p.network, _canonical(p.array)), []).append(i)
+            groups, a_all, pols_all, pes_all, distinct = _group_points(points)
+            tel.gauge("dse.fused.distinct_arrays", distinct)
 
         elapsed = 0.0
-        for (net, arr), rows in groups.items():
-            with tel.timed("dse.fused.group", configs=len(rows)):
-                adcs = tuple(sorted({points[i].array.adc_bits for i in rows}))
-                idx = np.asarray(rows)
-                a_idx = np.array(
-                    [adcs.index(points[i].array.adc_bits) for i in rows],
-                    dtype=np.int32,
-                )
-                pols = np.array([points[i].policy for i in rows], dtype=object)
-                pes = np.array([points[i].n_pes for i in rows], dtype=np.int64)
+        for net, arr, adcs, idx in groups:
+            with tel.timed("dse.fused.group", configs=len(idx)):
+                a_idx, pols, pes = a_all[idx], pols_all[idx], pes_all[idx]
             pipe = get_fused_pipeline(
                 net,
                 arr,

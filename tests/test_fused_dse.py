@@ -21,7 +21,7 @@ infeasible budgets rejected).
 import numpy as np
 import pytest
 
-from repro.core.cim.cost import DEFAULT_ARRAY
+from repro.core.cim.cost import DEFAULT_ARRAY, ArrayConfig
 from repro.dse import (
     FabricEval,
     allocate_batch,
@@ -32,7 +32,8 @@ from repro.dse import (
     run_fused_sweep,
     run_sweep,
 )
-from repro.dse.sweep import get_profiled, run_multichip_sweep
+from repro.dse import fused
+from repro.dse.sweep import SweepPoint, get_profiled, run_multichip_sweep
 
 ARRAYS = (DEFAULT_ARRAY, DEFAULT_ARRAY.variant(adc_bits=5))
 POLS = ("baseline", "weight_based", "perf_layerwise", "blockwise")
@@ -244,6 +245,105 @@ def test_chunking_bounds_device_footprint():
         == max(n_L, n_B) * per_config
     )
     assert snap_full["counters"]["dse.fused.chunks"] == 2  # one per family
+
+
+def _per_point_groups(points):
+    """The grouping as a per-point loop: ``_canonical`` on every point, then
+    each group's columns by list comprehension.  The reference for
+    ``fused._group_points``."""
+    groups: dict[tuple, list[int]] = {}
+    for i, p in enumerate(points):
+        groups.setdefault((p.network, fused._canonical(p.array)), []).append(i)
+    out = []
+    for (net, arr), rows in groups.items():
+        adcs = tuple(sorted({points[i].array.adc_bits for i in rows}))
+        a_idx = np.array(
+            [adcs.index(points[i].array.adc_bits) for i in rows], dtype=np.int32
+        )
+        pols = np.array([points[i].policy for i in rows], dtype=object)
+        pes = np.array([points[i].n_pes for i in rows], dtype=np.int64)
+        out.append((net, arr, adcs, np.asarray(rows), a_idx, pols, pes))
+    return out
+
+
+def _mixed_points(seed=0, pes=(300, 557, 800, 1024)):
+    """Two networks; equal arrays built as different objects; arrays that
+    differ only in ``adc_bits`` (one group) or in ``noc_hop_cycles`` or
+    geometry (groups of their own); in a seeded order."""
+    arrays = (
+        DEFAULT_ARRAY,
+        ArrayConfig(),
+        DEFAULT_ARRAY.variant(adc_bits=5),
+        ArrayConfig(adc_bits=5),
+        ArrayConfig(adc_bits=1),
+        DEFAULT_ARRAY.variant(noc_hop_cycles=4),
+        DEFAULT_ARRAY.variant(noc_hop_cycles=4, adc_bits=6),
+        DEFAULT_ARRAY.variant(rows=256, cols=256, adc_bits=2),
+    )
+    pts = [
+        SweepPoint(net, pol, n, arr)
+        for net in ("vgg11", "resnet18")
+        for arr in arrays
+        for pol in POLS
+        for n in pes
+    ]
+    return [pts[i] for i in np.random.default_rng(seed).permutation(len(pts))]
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_columnar_grouping_matches_per_point_loop(seed):
+    """Same groups in the same order, rows ascending, and bit-identical
+    ``adcs``/``a_idx``/``pols``/``pes`` for every group."""
+    points = _mixed_points(seed)
+    want = _per_point_groups(points)
+    groups, a_idx, pols, pes, distinct = fused._group_points(points)
+    assert distinct == len({(p.network, p.array) for p in points}) == 12
+    assert [g[:3] for g in groups] == [w[:3] for w in want]
+    assert len(want) == 6  # {vgg11, resnet18} x {default, noc 4, 256 rows}
+    for (_, _, _, rows), (net, arr, _, *cols) in zip(groups, want):
+        got = (rows, a_idx[rows], pols[rows], pes[rows])
+        for g, w in zip(got, cols):
+            assert g.dtype == w.dtype, (net, arr)
+            np.testing.assert_array_equal(g, w, err_msg=f"{net} {arr}")
+
+
+def test_canonical_once_per_distinct_array(monkeypatch):
+    """``_canonical`` runs once per distinct (network, array) pair of a call,
+    the same count in two calls on the same points (no state carried
+    across calls), and the ``dse.fused.distinct_arrays`` gauge reads it."""
+    from repro.fabric.telemetry import telemetry_session
+
+    calls = []
+    canonical = fused._canonical
+    monkeypatch.setattr(
+        fused, "_canonical", lambda a: calls.append(a) or canonical(a)
+    )
+
+    def fake_pipeline(net, arr, adcs, **kw):
+        def run(a_idx, pols, pes, **kw):
+            n = len(pols)
+            return {
+                "total_cycles": np.ones(n),
+                "images_per_sec": np.ones(n),
+                "layer_utilization": np.ones((n, 2)),
+                "arrays_used": np.ones(n, np.int64),
+                "arrays_total": np.ones(n, np.int64),
+            }
+
+        return run
+
+    monkeypatch.setattr(fused, "get_fused_pipeline", fake_pipeline)
+    points = _mixed_points(pes=tuple(range(300, 340)))
+    distinct = len({(p.network, p.array) for p in points})
+    counts, gauges = [], []
+    for _ in range(2):
+        calls.clear()
+        with telemetry_session() as tel:
+            fused.run_fused_sweep(points)
+            gauges.append(tel.snapshot()["gauges"]["dse.fused.distinct_arrays"])
+        counts.append(len(calls))
+    assert counts == [distinct, distinct] and distinct < len(points) // 100
+    assert gauges == [distinct, distinct]
 
 
 def test_latency_aware_is_rejected():
