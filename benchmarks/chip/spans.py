@@ -25,7 +25,6 @@ from pathlib import Path
 
 import trace_reduce
 
-PROGRAM = ("dse.", "vt.")  # names of the program's spans
 UNATTRIBUTED = "unattributed"
 TRACE_DIR = Path(__file__).resolve().parents[2] / ".bench_trace"
 
@@ -105,7 +104,7 @@ def idle_by_span(summary, xplane_path) -> dict[str, float] | None:
     spans = [
         (name, s, e)
         for name, s, e in _harness_line(ProfileData.from_file(str(xplane_path)))
-        if name.startswith(PROGRAM) and e > lo and s < hi
+        if name.startswith(trace_reduce.PROGRAM) and e > lo and s < hi
     ]
     if not spans:
         return None

@@ -11,9 +11,13 @@ import subprocess
 import sys
 from pathlib import Path
 
+import pytest
 from conftest import CHIP, REPO, TINY_REPLAY, TINY_SWEEP, make_checkout
 
 import bench
+import traffic
+
+SPEC = json.loads((REPO / "BENCHMARK.json").read_text())
 
 
 def test_every_cell_finds_its_files():
@@ -133,3 +137,28 @@ def test_benchmark_json_keeps_its_shape():
         reported = {m["name"] for m in plan.end_to_end}
         assert "setup_s" in reported and len(reported) >= 2 and plan.per_layer
     assert len(json.dumps(spec)) < 64 * 1024
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in SPEC["workloads"]])
+def test_every_cell_gets_the_per_layer_split_of_its_job_kind(cell):
+    """Each per-layer metric that moves the cell's rate, or its set-up,
+    names the cell or applies to every cell: a cell added later reads the
+    same split as the cells of its job kind before it."""
+    plan = bench.cell_plan(SPEC, cell)
+    moved = {traffic.load_kind(plan.mix["job"]).Job.rate_metric, "setup_s"}
+    for m in SPEC["per_layer"]:
+        if m["moves"] in moved:
+            assert bench._applies(m, cell), f"{m['name']} leaves out {cell}"
+
+
+@pytest.mark.parametrize("config", SPEC["configs"], ids=lambda c: c["name"])
+def test_every_configuration_is_the_network_the_program_runs(config):
+    """Layer for layer, with the arrays, blocks and least PEs the
+    configuration expects, on the configuration's own array."""
+    from repro.core import cim
+
+    c = json.loads((REPO / config["file"]).read_text())
+    arr = cim.DEFAULT_ARRAY.variant(**c["array"])
+    spec = cim.with_array(getattr(cim, c["spec"])(), arr)
+    traffic.check_spec(c, spec)
+    assert spec.min_pes(c["arrays_per_pe"]) == c["expect"]["min_pes"]

@@ -30,10 +30,10 @@ def _space(ops=OPS):
     host = (
         'planes { id: 1 name: "/host:CPU" '
         + _line(1, "python3", [(1, 10, 100), (2, 10, 5), (3, 15, 60), (2, 75, 5), (3, 80, 30),
-                               (4, 72, 20)])
+                               (4, 72, 20), (6, 71, 12)])
         + _line(2, "pjrt-tasks", [(5, 50, 60)])
         + _meta({1: "bench.traced", 2: "bench.gen", 3: "bench.job", 4: "np.asarray(jax.Array)",
-                 5: "Transpose"})
+                 5: "Transpose", 6: "vt.fetch"})
         + "} "
     )
     # modules: 20-40, 30-50 (overlap), 60-70, 90-100; ops inside them
@@ -69,11 +69,12 @@ def test_modules_and_ops(summary):
 
 def test_gaps_are_named_by_the_host(summary):
     gaps = [(round(s * 1e3), round(e * 1e3), n) for s, e, n in summary.gaps]
-    # the fetch covers most of 70-90; events of other threads name nothing
-    assert gaps == [(10, 20, "bench.gen"), (50, 60, "bench.job"), (70, 90, "np.asarray(jax.Array)"),
+    # the program's fetch span covers more than half of 70-90, jax's own
+    # fetch event more of it; jax's events and other threads' name nothing
+    assert gaps == [(10, 20, "bench.gen"), (50, 60, "bench.job"), (70, 90, "vt.fetch"),
                     (100, 110, "bench.job")]
     b = trace_reduce.breakdown(summary)
-    assert b["idle_gaps"][0] == ["np.asarray(jax.Array)", pytest.approx(0.020)]
+    assert b["idle_gaps"][0] == ["vt.fetch", pytest.approx(0.020)]
     assert sorted(n for n, _ in b["device_ops"]) == ["%copy.2", "%fusion.1"]
 
 
@@ -93,3 +94,25 @@ def test_a_trace_without_the_window_is_refused():
 
     with pytest.raises(ValueError):
         trace_reduce.reduce_profile(ProfileData.from_text_proto(_space().replace("bench.traced", "x")))
+
+
+# host notes (start, end, name) of one sweep job and the next job's inputs:
+# the harness's job around the program's sweep, its grouping, allocation
+# and a fetch
+JOB = [(0, 100, "bench.job"), (2, 98, "dse.fused.sweep"), (4, 10, "dse.fused.group"),
+       (10, 60, "dse.fused.allocate"), (60, 90, "dse.fused.fetch"), (100, 101, "bench.gen")]
+
+
+@pytest.mark.parametrize("gap, name", [
+    ((20, 50), "dse.fused.allocate"),  # nested notes: the innermost of those open
+    ((64, 86), "dse.fused.fetch"),
+    ((40, 65), "dse.fused.allocate"),  # straddles two spans: the one over half of it
+    ((52, 68), "dse.fused.sweep"),  # straddles two spans evenly: their parent
+    ((97, 99), "bench.job"),  # no program span open over half of it
+    ((96, 106), "bench.job"),  # no note over half of it: the one that covers most
+    ((100.25, 100.75), "bench.gen"),
+    ((200, 210), "host: none"),
+], ids=["nested", "nested-fetch", "straddle", "straddle-even", "no-program-span", "none-over-half",
+        "gen", "nothing"])
+def test_a_gap_is_named_by_the_innermost_note_over_half_of_it(gap, name):
+    assert trace_reduce._doing(JOB, *gap) == name

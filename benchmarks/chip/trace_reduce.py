@@ -8,10 +8,11 @@
 * device time per XLA module and per operation (``XLA Ops``, where the
   trace holds them), operations named by their HLO instruction;
 * the events of the host thread that ran the harness: its annotations
-  (``jax.profiler.TraceAnnotation``, named ``bench.*``) and what jax records
-  inside them (``PjitFunction(...)`` dispatches, ``np.asarray(jax.Array)``
-  fetches), which name what the host was doing in each idle gap of the
-  device.
+  (``jax.profiler.TraceAnnotation``, named ``bench.*``) and the program's
+  spans inside them (named ``dse.*`` or ``vt.*``), which name what the host
+  was doing in each idle gap of the device.  What jax records there
+  (``PjitFunction(...)`` dispatches, ``np.asarray(jax.Array)`` fetches)
+  names no gap.
 
 The traced window is the span of the ``bench.traced`` annotation.  Device
 and host events share the profiler's clock.
@@ -23,6 +24,7 @@ from dataclasses import dataclass, field
 
 WINDOW = "bench.traced"
 PREFIX = "bench."
+PROGRAM = ("dse.", "vt.")  # names of the program's spans
 
 
 @dataclass
@@ -76,7 +78,7 @@ def reduce_profile(pd) -> Summary:
                 for name, s, e in events:
                     if name == WINDOW:
                         window = (s, e)
-                    else:
+                    elif name.startswith((PREFIX, *PROGRAM)):
                         notes.append((s, e, name))
         elif plane.name.startswith("/device:") and "TPU" in plane.name and "Core" not in plane.name:
             devices.append(plane)
@@ -111,16 +113,19 @@ def reduce_profile(pd) -> Summary:
 
 
 def _doing(notes, s, e) -> str:
-    """The innermost host annotation that covers most of [s, e]."""
-    best, best_key = "host: none", (0.0, 0.0)
-    for ns, ne, name in notes:
-        cover = min(ne, e) - max(ns, s)
-        if cover <= 0:
-            continue
-        key = (cover, -(ne - ns))  # most overlap, then the shortest (innermost)
-        if key > best_key:
-            best, best_key = name, key
-    return best
+    """What the host was doing in the gap [s, e]: the shortest (innermost)
+    of the notes (harness annotations and program spans) that cover more
+    than half of it, so that a program span names the gap before the
+    harness's ``bench.job`` around it; where none covers half, the one that
+    covers most."""
+    covers = [(min(ne, e) - max(ns, s), ne - ns, name) for ns, ne, name in notes]
+    covers = [c for c in covers if c[0] > 0]
+    if not covers:
+        return "host: none"
+    over_half = [c for c in covers if c[0] > 0.5 * (e - s)]
+    if over_half:
+        return min(over_half, key=lambda c: c[1])[2]
+    return max(covers, key=lambda c: (c[0], -c[1]))[2]
 
 
 def load(path) -> Summary:
