@@ -9,12 +9,14 @@ configuration (``configs/<name>.json``), its traffic mix
 are files of their own, found by name.  The run sets up the program and
 warms up every shape of the cell with one job, then runs jobs back to back
 until ``--seconds`` have passed, fetching each job's results to host numpy.
-After the window it compares a sample of the window's outputs with the plain
-reference (``reference.py``) and prints, as the last line of standard
-output, one JSON object: ``correct``, ``attempted``, ``failed``,
-``metrics``, ``device`` (and with ``--trace 1`` the per-layer metrics and a
-``breakdown`` of the traced window), then ``checks``, each compared number
-beside its limit.  The same numbers close standard error.
+After the window it compares the set-up capture and a sample of the
+window's outputs with the plain reference that the configuration names
+(``reference.py`` where it names none; ``traffic.reference_of``) and
+prints, as the last line of standard output, one JSON object: ``correct``,
+``attempted``, ``failed``, ``metrics``, ``device`` (and with ``--trace 1``
+the per-layer metrics and a ``breakdown`` of the traced window), then
+``checks``, each compared number beside its limit.  The same numbers close
+standard error.
 
 It runs only on a TPU: with no accelerator, or fewer chips than the cell
 asks for, or without the program beside it, it exits non-zero and prints no

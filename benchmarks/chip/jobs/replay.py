@@ -6,7 +6,6 @@ from __future__ import annotations
 
 import numpy as np
 
-import reference as ref
 import traffic
 
 
@@ -30,12 +29,9 @@ class Job:
         from repro.fabric import TraceReplay, VirtualTimeFabric
 
         self.config, self.mix, self.seed = config, mix, seed
+        self.ref = ref = traffic.reference_of(config)
         self.trace_replay = TraceReplay
-        p = config["profile"]
-        spec, prof = get_profiled(
-            config["network"], profile_images=p["images"],
-            sample_patches=p["sample_patches"], seed=p["seed"],
-        )
+        spec, prof = get_profiled(config["network"], **traffic.profile_args(config))
         traffic.check_spec(config, spec)
         q = traffic.capture_samples(config)
         self.net = ref.Network(config, q, ref.Array(**config["array"]))
@@ -73,6 +69,7 @@ class Job:
 
     def _reference(self, inp, f):
         times, s = inp
+        ref = self.ref
         idx = ref.service_indices(
             s, [q.shape[0] for q in self.net.q], [l.patches for l in self.net.layers], self.n
         )

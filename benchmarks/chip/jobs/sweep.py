@@ -5,14 +5,21 @@ from __future__ import annotations
 
 import numpy as np
 
-import reference as ref
 import traffic
 
 
-def budgets(mix: dict, min_pes: int) -> range:
-    """Every whole PE count in ``pe_multiplier`` x min PEs, each once."""
+def budgets(mix: dict, min_pes: int) -> range | list[int]:
+    """Every whole PE count in ``pe_multiplier`` x min PEs, each once; with
+    ``pe_points`` (at least 2), that many counts spaced evenly between the
+    same two ends, both in, each once (fewer where rounding merges two)."""
     lo, hi = (float(x) for x in mix["pe_multiplier"])
-    return range(max(min_pes, int(np.ceil(min_pes * lo))), int(np.ceil(min_pes * hi)) + 1)
+    lo_n, hi_n = max(min_pes, int(np.ceil(min_pes * lo))), int(np.ceil(min_pes * hi))
+    if "pe_points" not in mix:
+        return range(lo_n, hi_n + 1)
+    n = mix["pe_points"]
+    if not isinstance(n, int) or n < 2:
+        raise SystemExit(f"pe_points must be a whole number of at least 2, not {n!r}")
+    return [int(x) for x in np.unique(np.rint(np.linspace(lo_n, hi_n, n)))]
 
 
 def order(n: int, seed: int, j: int) -> np.ndarray:
@@ -31,6 +38,7 @@ class Job:
         from repro.dse import SweepPoint, run_fused_sweep
 
         self.config, self.mix, self.seed = config, mix, seed
+        self.ref = ref = traffic.reference_of(config)
         self.run_sweep = run_fused_sweep
         base = ref.Array(**config["array"])
         self.variants = [(r, a) for r in mix["rows"] for a in mix["adc_bits"]]
@@ -76,7 +84,7 @@ class Job:
         """(configs, replica-vector length) per eval program family, for
         ``jobs`` jobs: per-layer vectors for the layer-wise policies, one
         entry per block for ``blockwise``."""
-        out = []
+        ref, out = self.ref, []
         L = len(self.config["layers"])
         base = ref.Array(**self.config["array"])
         for (rows, adc), b in zip(self.variants, self.budgets):
@@ -88,6 +96,7 @@ class Job:
     def compare(self, kept: list, control: bool = False) -> dict:
         """Sampled designs of the window against the float64 reference.  With
         ``control`` the float32 reference stands in the program's place."""
+        ref = self.ref
         q = traffic.capture_samples(self.config)
         base = ref.Array(**self.config["array"])
         k = self.mix["check_configs_per_variant_policy"]

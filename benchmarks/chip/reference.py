@@ -1,7 +1,10 @@
 """Plain reference of the CIM fabric model, written from the paper and the
 configuration files, importing nothing of the program under test.
 
-It decides ``correct`` for every cell.  Its only input from a run is the
+It decides ``correct`` for every cell whose configuration names no other
+reference module (``"reference": "<module>"``, for a file
+``benchmarks/chip/<module>.py`` whose name starts with ``reference``;
+``traffic.reference_of`` loads it).  Its only input from a run is the
 simulator's data: the quantized activation samples of the set-up capture
 (``sampled_q`` per layer), which are what a profile is computed from.  From
 those it derives every cycle count, statistic, allocation, throughput and
@@ -33,6 +36,21 @@ Model (arXiv:2008.06741, Sections II-V):
 
 ``dtype`` selects the arithmetic: float64 is the configuration's precision,
 float32 is the control that the comparison has to reject.
+
+What a reference module gives, and imports nothing of the program for:
+
+* the capture check, every kind: ``first_layer_samples(config, f)``, the
+  first crossbar layer's sampled quantized rows, ``f`` its arithmetic;
+* the ``sweep`` kind: ``Array(**config["array"])`` with
+  ``variant(rows, adc_bits)``; ``min_pes(config, array)`` and
+  ``n_blocks(config, array)``; ``Network(config, sampled_q, array)``;
+  ``allocate(net, policy, n_pes, f)``, a design; and ``evaluate(net,
+  design, f)``, its ``total_cycles``, ``images_per_sec``,
+  ``mean_utilization``, ``arrays_used`` and ``arrays_total``;
+* the ``replay`` kind: the ``sweep`` kind's, a ``Network`` with ``min_pes``,
+  ``q`` and ``layers`` (each with ``patches``, its jobs a request), and
+  ``service_indices(seed, samples, patches, n)`` and ``replay(net, designs,
+  arrivals, idx, f)``, the completion times.
 """
 
 from __future__ import annotations

@@ -39,22 +39,32 @@ def _metric(name, moves, cell):
             "layer": "test", "moves": moves, "workloads": [cell]}
 
 
-def make_checkout(tmp: Path, mixes: dict, metrics: dict | None = None, with_program=True):
+def make_checkout(tmp: Path, mixes: dict, metrics: dict | None = None, with_program=True,
+                  config: str = "vgg11", configs: dict | None = None, files: dict | None = None):
     """A checkout with the benchmark, the given traffic mixes dropped in as
-    ``traffic/<name>.json`` (one cell each, on VGG11), metric readers
-    ``metrics/<name>.py`` and, unless told otherwise, the program.  Returns
+    ``traffic/<name>.json`` (one cell each, ``<config>.<name>`` on the
+    configuration ``config``), configurations ``configs/<name>.json``,
+    metric readers ``metrics/<name>.py``, other ``files`` by their path in
+    ``benchmarks/chip`` and, unless told otherwise, the program.  Returns
     the checkout's own ``bench`` module."""
     chip = tmp / "benchmarks" / "chip"
     shutil.copytree(CHIP, chip, ignore=shutil.ignore_patterns("__pycache__", "tests"))
     for name, mix in mixes.items():
         (chip / "traffic" / f"{name}.json").write_text(json.dumps(mix))
+    for name, body in (configs or {}).items():
+        (chip / "configs" / f"{name}.json").write_text(json.dumps(body))
     for name, body in (metrics or {}).items():
         (chip / "metrics" / f"{name}.py").write_text(body)
+    for name, body in (files or {}).items():
+        (chip / name).write_text(body)
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
+    for name in configs or {}:
+        bench["configs"].append({"name": name, "source": "test", "reduced": [], "why": "test",
+                                 "file": f"benchmarks/chip/configs/{name}.json"})
     for name, mix in mixes.items():
-        cell = f"vgg11.{name}"
+        cell = f"{config}.{name}"
         rate = "dse_configs_per_s" if mix["job"] == "sweep" else "replay_requests_per_s"
-        bench["workloads"].append({"name": cell, "config": "vgg11", "traffic": name,
+        bench["workloads"].append({"name": cell, "config": config, "traffic": name,
                                    "chips": 1, "why": "test"})
         for m in bench["end_to_end"]:
             if m["name"] == rate:
@@ -70,17 +80,33 @@ def make_checkout(tmp: Path, mixes: dict, metrics: dict | None = None, with_prog
     return mod
 
 
+# the harness's modules, imported by plain name from the directory that a
+# run puts first on ``sys.path``, and the files that ``traffic`` loads
+HARNESS = {"bench", "control", "counts", "reference", "spans", "trace_reduce", "traffic"}
+
+
+def _harness_modules() -> set[str]:
+    return {k for k in sys.modules if k in HARNESS or k.startswith("bench_")}
+
+
 @pytest.fixture
 def checkout(tmp_path, monkeypatch):
-    """``checkout(mixes, metrics)`` -> the checkout's ``bench`` module, with
-    its look for a TPU skipped so that the rest of a run drives the CPU."""
+    """``checkout(mixes, metrics, **kw)`` -> the checkout's ``bench``
+    module, with its look for a TPU skipped so that the rest of a run drives
+    the CPU.  The run imports the checkout's own harness modules, and what
+    it imported and put on ``sys.path`` is undone after the test."""
 
     # no compile cache: the checkout's would be the repository's own
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jax_cache"))
+    monkeypatch.setattr(sys, "path", list(sys.path))
+    saved = {k: sys.modules.pop(k) for k in _harness_modules()}
 
-    def make(mixes, metrics=None):
-        mod = make_checkout(tmp_path, mixes, metrics)
+    def make(mixes, metrics=None, **kw):
+        mod = make_checkout(tmp_path, mixes, metrics, **kw)
         monkeypatch.setattr(mod, "require_device", lambda chips: None)
         return mod
 
-    return make
+    yield make
+    for k in _harness_modules():
+        del sys.modules[k]
+    sys.modules.update(saved)

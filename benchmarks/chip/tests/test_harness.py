@@ -3,6 +3,7 @@ refuses to run without a TPU or without the program."""
 
 from __future__ import annotations
 
+import dataclasses
 import json
 import os
 import re
@@ -10,7 +11,9 @@ import shutil
 import subprocess
 import sys
 from pathlib import Path
+from types import SimpleNamespace
 
+import numpy as np
 import pytest
 from conftest import CHIP, REPO, TINY_REPLAY, TINY_SWEEP, make_checkout
 
@@ -18,6 +21,11 @@ import bench
 import traffic
 
 SPEC = json.loads((REPO / "BENCHMARK.json").read_text())
+# what a reference module gives each job kind: reference.py's docstring
+REFERENCE_API = {
+    "sweep": ["first_layer_samples", "Array", "min_pes", "n_blocks", "Network", "allocate", "evaluate"],
+}
+REFERENCE_API["replay"] = REFERENCE_API["sweep"] + ["service_indices", "replay"]
 
 
 def test_every_cell_finds_its_files():
@@ -29,6 +37,8 @@ def test_every_cell_finds_its_files():
         plan = bench.cell_plan(spec, w["name"])
         assert plan.config["name"] == w["config"]
         assert plan.mix["job"] in ("sweep", "replay")
+        ref = traffic.reference_of(plan.config)
+        assert all(hasattr(ref, name) for name in REFERENCE_API[plan.mix["job"]])
         assert [m["name"] for m in plan.end_to_end if m["name"] != "setup_s"]
         assert plan.per_layer
         for m in plan.per_layer:
@@ -162,3 +172,47 @@ def test_every_configuration_is_the_network_the_program_runs(config):
     spec = cim.with_array(getattr(cim, c["spec"])(), arr)
     traffic.check_spec(c, spec)
     assert spec.min_pes(c["arrays_per_pe"]) == c["expect"]["min_pes"]
+
+
+@dataclasses.dataclass(frozen=True)
+class _GroupedLayer:
+    """A layer type with one field beyond the conv configurations' six."""
+
+    name: str
+    kernel: int
+    cin: int
+    cout: int
+    out_hw: int
+    stride: int
+    groups: int
+
+
+@pytest.mark.parametrize("groups, refused", [(4, False), (2, True)], ids=["same", "extra-column-differs"])
+def test_check_spec_reads_the_configurations_own_columns(groups, refused):
+    cols = ["name", "kernel", "cin", "cout", "out_hw", "stride", "groups"]
+    config = {"network": "grouped", "layer_columns": cols, "layers": [["g1", 3, 64, 64, 8, 1, 4]],
+              "expect": {"n_arrays": 5, "n_blocks": 5}}
+    spec = SimpleNamespace(layers=[_GroupedLayer("g1", 3, 64, 64, 8, 1, groups)], n_arrays=5, n_blocks=5)
+    if refused:
+        with pytest.raises(SystemExit, match="layers differ"):
+            traffic.check_spec(config, spec)
+    else:
+        traffic.check_spec(config, spec)
+
+
+@pytest.mark.parametrize("extra", [{}, {"calib_tokens": 512, "batch": 4}], ids=["none", "two"])
+def test_every_other_profile_key_reaches_the_capture(monkeypatch, extra):
+    import repro.dse.sweep as program_sweep
+
+    seen = {}
+
+    def stub(network, **kw):
+        seen.update(network=network, **kw)
+        return SimpleNamespace(layers=[SimpleNamespace(sampled_q=np.zeros((2, 3), np.uint8))])
+
+    monkeypatch.setattr(program_sweep, "get_captured", stub)
+    vgg11 = json.loads((CHIP / "configs" / "vgg11.json").read_text())
+    config = {**vgg11, "profile": {**vgg11["profile"], **extra}}
+    q = traffic.capture_samples(config)
+    assert seen == {"network": "vgg11", "profile_images": 1, "sample_patches": 128, "seed": 0, **extra}
+    assert [a.shape for a in q] == [(2, 3)]
