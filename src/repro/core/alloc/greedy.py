@@ -610,9 +610,12 @@ class GreedyEventSchedule:
         """Replica counts for C budgets — element-wise identical to running
         ``greedy_allocate`` (or the lock-step batch kernel) per budget.
 
-        Distinct budgets are answered from one incremental walk over the
-        event list: O(E + U*N + C log E) for U distinct stopping points,
-        instead of the kernel's O(iters * C * N).
+        Distinct stopping points are answered all at once: each event
+        before the last stop is tagged with the first stop that includes it,
+        one ``bincount`` counts grants per (stop, unit), and a running sum
+        over the stops gives every snapshot — O(E + U*N + C log E) for U
+        distinct stops, instead of the kernel's O(iters * C * N).  Counts
+        are integers, so the snapshots are exact.
         """
         b = np.atleast_1d(np.asarray(budgets, dtype=np.float64))
         if b.size and b.max() > self.max_budget:
@@ -624,16 +627,13 @@ class GreedyEventSchedule:
         n = self.n_units
         m = np.searchsorted(self.cum_cost, b, side="right")
         uniq, inv = np.unique(m, return_inverse=True)
-        snaps = np.empty((uniq.size, n), dtype=np.int64)
-        counts = self.r0.copy()
-        prev = 0
-        for j, stop in enumerate(uniq):
-            if stop > prev:
-                counts = counts + np.bincount(
-                    self.unit[prev:stop], minlength=n
-                )
-                prev = int(stop)
-            snaps[j] = counts
+        # events uniq[j-1] <= e < uniq[j] are segment j (the first stop
+        # that includes them); events past the last stop are left out
+        seg = np.repeat(np.arange(uniq.size), np.diff(uniq, prepend=0))
+        grants = np.bincount(
+            seg * n + self.unit[: seg.size], minlength=uniq.size * n
+        ).reshape(uniq.size, n)
+        snaps = np.cumsum(grants, axis=0) + self.r0
         replicas = snaps[inv]
         spent = (
             np.where(m > 0, self.cum_cost[np.maximum(m - 1, 0)], 0.0)

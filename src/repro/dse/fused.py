@@ -545,26 +545,32 @@ class FusedPipeline:
             sel = (a_idx + np.where(zskip, A, 0)).astype(np.int32)
 
             # ---- stage 2, host side: every replica vector from shared
-            # tables.  Proportional replicas are MACs-only config constants
-            # (the staged largest-remainder routine, exact); the greedy
-            # families replay the per-variant event schedules — element-wise
-            # identical to the lock-step kernel, at a searchsorted per config
-            # instead of a bisection + residual loop over (C, N) tensors per
-            # chunk.
+            # tables, each distinct answer computed once and gathered to
+            # its rows.  Proportional replicas are MACs-only constants of
+            # the budget (the staged largest-remainder routine, exact); the
+            # greedy families replay the per-variant event schedules —
+            # element-wise identical to the lock-step kernel, at a
+            # searchsorted per config instead of a bisection + residual
+            # loop over (C, N) tensors per chunk.  Arrays used come from
+            # each table's own ``spent`` (exact integers, as the replicas).
             r_layer = np.ones((C, self.L))  # rows of family "L" only
-            prop = kind == 0
-            if prop.any():
+            used_f = np.zeros(C)
+            distinct = 0
+            prop = np.nonzero(kind == 0)[0]
+            if prop.size:
+                ub, inv = np.unique(budgets[prop], return_inverse=True)
                 res = proportional_allocate_batch(
-                    self.macs, self.layer_arrays, budgets[prop]
+                    self.macs, self.layer_arrays, ub
                 )
-                r_layer[prop] = res.replicas.astype(np.float64)
+                r_layer[prop] = res.replicas[inv]
+                used_f[prop] = res.spent[inv]
+                distinct += ub.size
             if engine == "pallas":
                 return self._pallas_eval(
                     sel, a_idx, kind, budgets, layerwise, zskip, r_layer, total,
                     int(n_images), float(clock_hz), int(chunk), need_dups,
                     return_bank,
                 )
-            used_f = np.zeros(C)
             rows_B = np.nonzero(kind == 2)[0]
             r_blk = np.ones((rows_B.size, self.N))  # family "B", rows_B order
             for k, rows_k in ((1, np.nonzero(kind == 1)[0]), (2, rows_B)):
@@ -573,16 +579,19 @@ class FusedPipeline:
                 bmax = float(budgets[rows_k].max())
                 for a in np.unique(a_idx[rows_k]):
                     rk = a_idx[rows_k] == a
+                    rows = rows_k[rk]
                     got = self._schedule(k, int(a), bmax).replicas_at(
-                        budgets[rows_k[rk]]
+                        budgets[rows]
                     )
                     if k == 1:
-                        r_layer[rows_k[rk]] = got.replicas.astype(np.float64)
+                        r_layer[rows] = got.replicas
                     else:
-                        r_blk[rk] = got.replicas.astype(np.float64)
+                        r_blk[rk] = got.replicas
+                    used_f[rows] = got.spent
+                    # one snapshot per distinct stop, and stops differ in spent
+                    distinct += np.unique(got.spent).size
             rows_L = np.nonzero(kind != 2)[0]
-            used_f[rows_L] = (r_layer[rows_L] - 1.0) @ self.layer_arrays
-            used_f[rows_B] = ((r_blk - 1.0) * self.cost_blk).sum(axis=1)
+        tel.gauge("dse.fused.alloc_distinct", distinct)
 
         outs = {
             "total_cycles": np.zeros(C),

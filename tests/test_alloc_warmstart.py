@@ -156,6 +156,76 @@ def test_event_schedule_zero_and_tiny_budgets():
     np.testing.assert_array_equal(got.leftover, [0.0, 2.0])
 
 
+def _replicas_at_walk(sched, budgets):
+    """The incremental walk ``replicas_at`` answered with before its
+    segmented count: one ``bincount`` and one snapshot per distinct stop."""
+    b = np.atleast_1d(np.asarray(budgets, dtype=np.float64))
+    n = sched.n_units
+    m = np.searchsorted(sched.cum_cost, b, side="right")
+    uniq, inv = np.unique(m, return_inverse=True)
+    snaps = np.empty((uniq.size, n), dtype=np.int64)
+    counts = sched.r0.copy()
+    prev = 0
+    for j, stop in enumerate(uniq):
+        if stop > prev:
+            counts = counts + np.bincount(sched.unit[prev:stop], minlength=n)
+            prev = int(stop)
+        snaps[j] = counts
+    replicas = snaps[inv]
+    spent = (
+        np.where(m > 0, sched.cum_cost[np.maximum(m - 1, 0)], 0.0)
+        if len(sched)
+        else np.zeros(b.size)
+    )
+    return replicas, spent, b - spent, sched.base / replicas
+
+
+def _walk_case(case, rng):
+    """(schedule, budgets) for one edge of the segmented count."""
+    n = int(rng.integers(1, 30))
+    base = rng.integers(1, 200, size=n).astype(np.float64)
+    cost = rng.integers(1, 6, size=n).astype(np.float64)
+    W = float(rng.integers(20, 300))
+    r0 = rng.integers(1, 4, size=n) if case == "warm_start" else None
+    if case == "empty_table":
+        W = float(cost.min()) - 1.0
+    sched = greedy_event_schedule(base, cost, W, initial_replicas=r0)
+    budgets = rng.integers(0, int(W) + 1, size=int(rng.integers(1, 40)))
+    if case == "repeated_budgets":
+        budgets = rng.choice(budgets[:3], size=50)
+    elif case == "budget_on_cum_cost" and len(sched):
+        budgets = rng.choice(sched.cum_cost, size=20)
+    elif case == "budget_zero":
+        budgets = np.concatenate([[0], budgets, [0]])
+    return sched, budgets.astype(np.float64)
+
+
+_WALK_CASES = (
+    "cold", "warm_start", "repeated_budgets", "budget_on_cum_cost",
+    "budget_zero", "empty_table",
+)
+
+
+@pytest.mark.parametrize("case", _WALK_CASES)
+def test_replicas_at_equals_incremental_walk(case):
+    """The segmented count answers every budget exactly as the per-stop
+    walk does: replicas, spent, remaining and latency, bit for bit."""
+    rng = np.random.default_rng(_WALK_CASES.index(case))
+    for trial in range(25):
+        sched, budgets = _walk_case(case, rng)
+        if case == "empty_table":
+            assert len(sched) == 0
+        got = sched.replicas_at(budgets)
+        want = _replicas_at_walk(sched, budgets)
+        for name, g, w in zip(
+            ("replicas", "spent", "leftover", "latency"),
+            (got.replicas, got.spent, got.leftover, got.latency),
+            want,
+        ):
+            assert g.dtype == w.dtype, f"trial {trial} {name}"
+            np.testing.assert_array_equal(g, w, err_msg=f"trial {trial} {name}")
+
+
 # ------------------------------------------------------- proportional edges
 def test_proportional_zero_budget():
     w = np.array([5.0, 1.0, 3.0])

@@ -247,6 +247,58 @@ def test_chunking_bounds_device_footprint():
     assert snap_full["counters"]["dse.fused.chunks"] == 2  # one per family
 
 
+def _alloc_call(pes=(557, 300, 800, 300, 1024)):
+    """A call mixing every fused policy, both ADC variants and repeated PE
+    budgets, with the replica tensor fetched."""
+    from repro.fabric.telemetry import telemetry_session
+
+    pipe = get_fused_pipeline("vgg11", DEFAULT_ARRAY, (6, 8))
+    a_idx, pols, pes = _packed_grid(pols=POLS + ("weight_blockflow",), pes=pes)
+    with telemetry_session() as tel:
+        out = pipe(a_idx, pols, pes, need_dups=True)
+        snap = tel.snapshot()
+    budgets = (pes * pipe.arrays_per_pe - pipe.base_arrays).astype(np.float64)
+    return pipe, a_idx, pols, budgets, out, snap
+
+
+def test_fused_allocation_rows_and_arrays_used():
+    """Proportional rows answered once per distinct budget equal
+    ``proportional_allocate_batch`` run row by row, and ``arrays_used``
+    (from each table's ``spent``) equals the replica vector's own cost."""
+    from repro.core.alloc.greedy import proportional_allocate_batch
+
+    pipe, _, pols, budgets, out, _ = _alloc_call()
+    dups = out["dups_lb"]
+    blockwise = pols == "blockwise"
+    for c, pol in enumerate(pols):
+        if blockwise[c]:
+            r, cost = dups[c, pipe.l_idx, pipe.blk_idx], pipe.cost_blk
+        else:
+            r, cost = dups[c, :, 0], pipe.layer_arrays
+        if fused._KIND[pol] == 0:
+            want = proportional_allocate_batch(
+                pipe.macs, pipe.layer_arrays, budgets[c : c + 1]
+            )
+            np.testing.assert_array_equal(r, want.replicas[0], err_msg=f"row {c}")
+        assert out["arrays_used"][c] == pipe.base_arrays + ((r - 1) * cost).sum()
+
+
+def test_alloc_distinct_gauge_counts_each_answer_once():
+    """``dse.fused.alloc_distinct`` is the distinct proportional budgets
+    plus, per greedy family and ADC variant, the distinct stops."""
+    pipe, a_idx, pols, budgets, _, snap = _alloc_call()
+    kind = np.array([fused._KIND[p] for p in pols])
+    want = np.unique(budgets[kind == 0]).size
+    for k in (1, 2):
+        rows = kind == k
+        for a in np.unique(a_idx[rows]):
+            sched = pipe._schedule(k, int(a), float(budgets[rows].max()))
+            b = budgets[rows & (a_idx == a)]
+            want += np.unique(np.searchsorted(sched.cum_cost, b, "right")).size
+    assert want < len(pols)
+    assert snap["gauges"]["dse.fused.alloc_distinct"] == want
+
+
 def _per_point_groups(points):
     """The grouping as a per-point loop: ``_canonical`` on every point, then
     each group's columns by list comprehension.  The reference for
