@@ -29,9 +29,7 @@ __all__ = [
     "PlacedAllocationResult",
     "erlang_c",
     "greedy_allocate",
-    "greedy_allocate_batch",
     "greedy_release",
-    "greedy_batch_kernel",
     "greedy_event_schedule",
     "greedy_allocate_placed",
     "place_extras",
@@ -420,141 +418,6 @@ class BatchAllocationResult:
         return self.replicas.shape[0]
 
 
-_GREEDY_BATCH_JIT: dict = {}
-
-
-def greedy_batch_kernel(base, cost, budget, r0):
-    """The lock-step batched greedy as a TRACEABLE jax function.
-
-    (C, N) ``base`` latencies / ``cost`` per replica, (C,) ``budget``,
-    (C, N) ``r0`` initial replicas -> (replicas (C, N) float, leftover (C,)).
-    Plain jax ops end to end, so callers may either jit it standalone
-    (``greedy_allocate_batch``) or inline it inside a larger traced program
-    — the fused DSE pipeline (``repro.dse.fused``) calls it between the
-    in-graph profile derivation and the vmapped throughput kernel, with no
-    host round-trip on either side.
-
-    Two phases, both exactly replicating the scalar heap loop:
-
-    1.  *Bulk water-fill by bisection.*  The greedy's max-latency is
-        non-increasing, so for any makespan target ``lam`` the state
-        ``r_i = max(r0_i, ceil(base_i / lam))`` is a state the scalar greedy
-        passes through — provided its cost fits the budget (every
-        intermediate grant is then affordable, so the scalar stopping rule
-        cannot fire early).  We bisect ``lam`` to the tightest affordable
-        state, then back off by 1e-9 relative so grants at levels within
-        roundoff of the boundary are left to phase 2 (whose tie-breaking is
-        exact) rather than resolved by float ceil.
-    2.  *Lock-step residual loop.*  Grant the argmax-latency unit of every
-        config one replica per iteration; a config freezes the moment its
-        argmax is unaffordable (the paper's stopping rule — argmax ties
-        resolve to the lowest index, matching the scalar heap order).
-    """
-    import jax
-    import jax.numpy as jnp
-
-    N = base.shape[1]
-
-    def r_of(lam):
-        return jnp.maximum(r0, jnp.ceil(base / lam[:, None]))
-
-    def spend_of(r):
-        return ((r - r0) * cost).sum(axis=1)
-
-    lat0 = base / r0
-    hi = jnp.maximum(lat0.max(axis=1), 1e-300)  # degenerate all-zero rows
-    min_cost = cost.min(axis=1)
-    # strictly below the final greedy makespan -> provably infeasible
-    lo = hi / (2.0 * (2.0 + jnp.maximum(budget, 0.0) / min_cost))
-
-    def bisect(_, lohi):
-        lo, hi = lohi
-        mid = 0.5 * (lo + hi)
-        feasible = spend_of(r_of(mid)) <= budget
-        return jnp.where(feasible, lo, mid), jnp.where(feasible, mid, hi)
-
-    lo, hi = jax.lax.fori_loop(0, 80, bisect, (lo, hi))
-    r = r_of(hi * (1.0 + 1e-9))
-    rem = budget - spend_of(r)
-
-    idx = jnp.arange(N)
-
-    def not_done(state):
-        return ~state[2].all()
-
-    def grant(state):
-        r, rem, done = state
-        lat = base / r
-        i = lat.argmax(axis=1)  # first max == scalar heap tie order
-        ci = jnp.take_along_axis(cost, i[:, None], axis=1)[:, 0]
-        ok = (ci <= rem) & ~done
-        r = r + ((idx[None, :] == i[:, None]) & ok[:, None])
-        rem = rem - jnp.where(ok, ci, 0.0)
-        return r, rem, done | ~ok
-
-    done = jnp.zeros(base.shape[0], dtype=bool)
-    r, rem, done = jax.lax.while_loop(not_done, grant, (r, rem, done))
-    return r, rem
-
-
-def _greedy_batch_kernel():
-    """Build (once) the standalone jitted entry over ``greedy_batch_kernel``."""
-    import jax
-
-    return jax.jit(greedy_batch_kernel)
-
-
-def greedy_allocate_batch(
-    base_latency: np.ndarray,
-    unit_cost: np.ndarray,
-    budgets: np.ndarray,
-    *,
-    initial_replicas: np.ndarray | None = None,
-) -> BatchAllocationResult:
-    """Vectorized ``greedy_allocate`` over C configs, lock-step in jnp.
-
-    ``base_latency`` / ``unit_cost`` / ``initial_replicas`` broadcast from
-    (N,) to (C, N); ``budgets`` is (C,).  Replica counts are element-wise
-    identical to looping the scalar allocator (the property suite pins
-    this); ``spent`` / ``leftover`` agree to float roundoff.  Runs in
-    float64 under ``core.precision.x64``.
-    """
-    budgets = np.atleast_1d(np.asarray(budgets, dtype=np.float64))
-    C = budgets.shape[0]
-    base = np.atleast_1d(np.asarray(base_latency, dtype=np.float64))
-    cost = np.atleast_1d(np.asarray(unit_cost, dtype=np.float64))
-    if base.shape[-1] != cost.shape[-1]:
-        raise ValueError(f"base_latency {base.shape} vs unit_cost {cost.shape}")
-    N = base.shape[-1]
-    base = np.ascontiguousarray(np.broadcast_to(base, (C, N)))
-    cost = np.ascontiguousarray(np.broadcast_to(cost, (C, N)))
-    if np.any(cost <= 0):
-        raise ValueError("unit_cost must be strictly positive")
-    if initial_replicas is None:
-        r0 = np.ones((C, N))
-    else:
-        r0 = np.ascontiguousarray(
-            np.broadcast_to(np.asarray(initial_replicas, dtype=np.float64), (C, N))
-        )
-        if np.any(r0 < 1):
-            raise ValueError("every unit needs at least one replica")
-    if N == 0:
-        return BatchAllocationResult(
-            np.ones((C, 0), dtype=np.int64), base.copy(), np.zeros(C), budgets.copy()
-        )
-
-    from ..precision import x64
-
-    if "kernel" not in _GREEDY_BATCH_JIT:
-        _GREEDY_BATCH_JIT["kernel"] = _greedy_batch_kernel()
-    with x64():
-        r, rem = _GREEDY_BATCH_JIT["kernel"](base, cost, budgets, r0)
-    r = np.asarray(r)
-    replicas = r.astype(np.int64)
-    spent = ((r - r0) * cost).sum(axis=1)
-    return BatchAllocationResult(replicas, base / r, spent, np.asarray(rem))
-
-
 @dataclass(frozen=True)
 class GreedyEventSchedule:
     """The greedy grant sequence as a static, budget-independent table.
@@ -580,9 +443,9 @@ class GreedyEventSchedule:
         and ``searchsorted(cum, W, side="right")`` IS the stopping rule.
 
     One schedule therefore answers EVERY budget on the same base latencies
-    in O(log E) — this is what lets the fused DSE pipeline replace a
-    per-chunk bisection + residual ``while_loop`` over (C, N) tensors with
-    a single shared table per ADC variant (``repro.dse.fused``).
+    in O(log E).  It is the batched greedy of both DSE paths: the staged
+    ``dse.engine.allocate_batch`` and the fused pipeline, which keeps one
+    shared table per ADC variant (``repro.dse.fused``).
 
     Attributes:
       unit: (E,) int64 — receiving unit of each event, priority order.
@@ -608,14 +471,13 @@ class GreedyEventSchedule:
 
     def replicas_at(self, budgets: np.ndarray) -> BatchAllocationResult:
         """Replica counts for C budgets — element-wise identical to running
-        ``greedy_allocate`` (or the lock-step batch kernel) per budget.
+        ``greedy_allocate`` per budget.
 
         Distinct stopping points are answered all at once: each event
         before the last stop is tagged with the first stop that includes it,
         one ``bincount`` counts grants per (stop, unit), and a running sum
         over the stops gives every snapshot — O(E + U*N + C log E) for U
-        distinct stops, instead of the kernel's O(iters * C * N).  Counts
-        are integers, so the snapshots are exact.
+        distinct stops.  Counts are integers, so the snapshots are exact.
         """
         b = np.atleast_1d(np.asarray(budgets, dtype=np.float64))
         if b.size and b.max() > self.max_budget:

@@ -19,13 +19,14 @@ every model family resolves through one table:
 
 from __future__ import annotations
 
-from typing import Any
+from typing import TYPE_CHECKING, Any
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..models.config import ModelConfig
+if TYPE_CHECKING:
+    from ..models.config import ModelConfig
 
 __all__ = [
     "batch_axes",

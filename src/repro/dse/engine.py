@@ -3,9 +3,10 @@
 ``allocate_batch`` mirrors ``core.cim.simulate.allocate`` policy-for-policy
 but runs every config of a sweep at once: the proportional policies reuse the
 scalar largest-remainder routine (cheap, exact), while the greedy policies —
-the paper's actual algorithm and the sweep hot path — go through the
-lock-step ``greedy_allocate_batch``.  Replica vectors are element-wise
-identical to the scalar allocator; the golden-equivalence suite pins this.
+the paper's actual algorithm and the sweep hot path — answer every budget
+from one ``greedy_event_schedule`` per family, the same batched greedy the
+fused pipeline uses.  Replica vectors are element-wise identical to the
+scalar allocator; the golden-equivalence suite pins this.
 
 ``run_batch`` chains it into ``BatchSimulator`` (vmapped float64 kernel) so a
 (policy, PE-count) sweep over one profiled network is two jit calls.
@@ -17,7 +18,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
-from ..core.alloc.greedy import greedy_allocate_batch, proportional_allocate_batch
+from ..core.alloc.greedy import greedy_event_schedule, proportional_allocate_batch
 from ..core.cim.network import NetworkSpec
 from ..core.cim.profile import NetworkProfile
 from ..core.cim.simulate import (
@@ -35,43 +36,12 @@ from ..core.cim.simulate import (
 __all__ = [
     "AllocationBatch",
     "allocate_batch",
-    "flat_unit_map",
     "run_batch",
     "to_allocation",
 ]
 
 _PROPORTIONAL = ("baseline", "weight_based", "weight_blockflow")
 _LAYERWISE_FLOW = ("baseline", "weight_based", "perf_layerwise")
-
-
-def flat_unit_map(
-    L: int,
-    B: int,
-    l_idx: np.ndarray | None = None,
-    blk_idx: np.ndarray | None = None,
-) -> np.ndarray:
-    """One-hot (N, L, B) map from a flat allocation-unit axis to the dense
-    replica tensor — the shared representation of BOTH greedy families.
-
-    ``l_idx is None`` builds the per-LAYER family (perf_layerwise and the
-    proportional policies): N = L units, each broadcasting its replicas
-    across every block column of its layer.  With ``l_idx``/``blk_idx``
-    (from ``NetworkSpec.block_table``) it builds the per-BLOCK family
-    (blockwise): each unit owns exactly its (layer, block) cell.  Replica
-    scatters become the exact matmul ``dups = 1 + (r - 1) @ map`` (one
-    nonzero * 1.0 per cell), which is how the Pallas fused allocate+eval
-    kernel (``kernels.fused_alloc_eval``) keeps both families in one
-    kernel body.
-    """
-    if l_idx is None:
-        u = np.zeros((L, L, B))
-        u[np.arange(L), np.arange(L), :] = 1.0
-        return u
-    l_idx = np.asarray(l_idx, dtype=np.int64)
-    blk_idx = np.asarray(blk_idx, dtype=np.int64)
-    u = np.zeros((l_idx.size, L, B))
-    u[np.arange(l_idx.size), l_idx, blk_idx] = 1.0
-    return u
 
 
 @dataclass(frozen=True)
@@ -101,10 +71,10 @@ def allocate_batch(
     """Batched ``allocate``: one call for a whole (policy, PE-count) sweep.
 
     ``latency_aware`` points are supported but allocate through the scalar
-    path per config (the queueing greedy is load-dependent and not
-    lock-steppable); their offered load is ``latency_load_frac`` times the
-    scalar blockwise throughput at the same budget, matching the scalar
-    ``allocate`` default."""
+    path per config (the queueing greedy is load-dependent, so no shared
+    grant table answers it); their offered load is ``latency_load_frac``
+    times the scalar blockwise throughput at the same budget, matching the
+    scalar ``allocate`` default."""
     policies = np.atleast_1d(np.asarray(policies, dtype=object))
     n_pes = np.atleast_1d(np.asarray(n_pes, dtype=np.int64))
     policies, n_pes = np.broadcast_arrays(policies, n_pes)
@@ -138,20 +108,23 @@ def allocate_batch(
     perf = policies == "perf_layerwise"
     if perf.any():
         exp_lat = np.array([cyc[i].max(axis=1).mean() * ppi[i] for i in range(L)])
-        res = greedy_allocate_batch(exp_lat, layer_arrays, free[perf])
+        # array costs and budgets are integers: the schedule is exact
+        res = greedy_event_schedule(
+            exp_lat, layer_arrays, float(free[perf].max())
+        ).replicas_at(free[perf])
         dups_lb[perf] = res.replicas[:, :, None].astype(np.float64)
-        used[perf] = base_arrays + ((res.replicas - 1) @ layer_arrays).astype(np.int64)
+        used[perf] = base_arrays + res.spent.astype(np.int64)
 
     block = policies == "blockwise"
     if block.any():
         base_lat, cost = blockwise_units(spec, [cyc[i].mean(axis=0) for i in range(L)])
-        res = greedy_allocate_batch(base_lat, cost, free[block])
+        res = greedy_event_schedule(
+            base_lat, cost, float(free[block].max())
+        ).replicas_at(free[block])
         table = spec.block_table()  # (n_blocks, 3): layer, block-in-layer, width
         rows = np.flatnonzero(block)
         dups_lb[rows[:, None], table[None, :, 0], table[None, :, 1]] = res.replicas
-        used[block] = base_arrays + ((res.replicas - 1) * cost).sum(axis=1).astype(
-            np.int64
-        )
+        used[block] = base_arrays + res.spent.astype(np.int64)
 
     for i in np.flatnonzero(policies == "latency_aware"):
         a = allocate(

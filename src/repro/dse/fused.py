@@ -2,20 +2,19 @@
 
 The staged sweep (``run_sweep``) dispatches three separately-jitted stages
 per (network, array) group — host-side ``derive_profile`` views per ADC
-variant, the lock-step batched allocators, and the vmapped throughput
-kernel — with host round-trips (and profile-cache traffic) between every
-pair.  This module collapses them around ONE derive per (network,
-rows-geometry) group: the per-ADC bit-plane cycle banks come from the
-shared ``capture_activations`` capture *in-graph*
+variant, the batched allocators, and the vmapped throughput kernel — with
+host round-trips (and profile-cache traffic) between every pair.  This
+module collapses them around ONE derive per (network, rows-geometry)
+group: the per-ADC bit-plane cycle banks come from the shared
+``capture_activations`` capture *in-graph*
 (``kernels.bitplane_profile.bitplane_cycle_bank``: shift-and-mask popcount
 + multi-ADC zero-skip re-costing), stacked once and kept device-resident
 across every chunk of every call.  Allocation exploits the same sharing:
 each greedy family's base latencies are per-ADC-variant constants, so the
-whole lock-step greedy is replayed from ONE sorted grant-event table per
-variant (``core.alloc.greedy.greedy_event_schedule`` — exact, heap-order
-tie-for-tie) at a ``searchsorted`` per config, instead of a bisection +
-residual ``while_loop`` over (C, N) tensors per dispatch.  The per-chunk
-traced program is then pure scatter + vmapped ``_eval_kernel``, with each
+whole greedy is replayed from ONE sorted grant-event table per variant
+(``core.alloc.greedy.greedy_event_schedule`` — exact, heap-order
+tie-for-tie) at a ``searchsorted`` per config.  The per-chunk traced
+program is then pure scatter + vmapped ``_eval_kernel``, with each
 config gathering its variant's banks by one scalar ``sel`` INSIDE the
 kernel — so nothing (C, L, B)-shaped exists besides the replica tensor
 and a whole (ADC x policy x PE-budget) config tensor streams through with
@@ -34,9 +33,9 @@ bit-identity:
   * cycle samples are integer-valued float64, so any summation order gives
     the exact integer sum (all partials < 2^53), and each per-block mean is
     that exact sum divided once by the patch count — bit-equal to
-    ``_pack_profile``'s.  The greedy allocators then run the very same
-    kernel body on those bit-equal inputs, which is why the replica
-    tensors are EXACTLY equal, not merely close;
+    ``_pack_profile``'s.  Both paths then answer the greedy from the very
+    same event schedule on those bit-equal inputs, which is why the
+    replica tensors are EXACTLY equal, not merely close;
   * but the staged and fused evaluators are *different XLA programs*, and
     op-fusion choices between two compilations can shift the last ULP of
     the rounded mean->multiply->divide chains (observed: 1 config in 24 on
@@ -45,8 +44,6 @@ bit-identity:
     order each backend picks.  Float columns are therefore compared at
     rtol 1e-12 — four orders looser than the ULP wobble, tight enough that
     any real formula drift fails;
-  * the greedy allocators run the very same kernel body on bit-equal base
-    latencies, so replica vectors are exactly equal;
   * the proportional policies read NO profile data (MACs only), so their
     replica vectors are precomputed host-side with the same
     largest-remainder routine the staged path uses (this also sidesteps
@@ -114,19 +111,6 @@ _KIND["perf_layerwise"] = 1
 _KIND["blockwise"] = 2
 
 _PIPELINE_CACHE: dict[tuple, "FusedPipeline"] = {}
-
-
-def _check_engine(engine: str) -> None:
-    """Reject an unknown engine, and ``"pallas"`` on a TPU, before any
-    capture or derive work is spent."""
-    if engine not in ("xla", "pallas"):
-        raise ValueError(f"unknown engine {engine!r}; use 'xla' or 'pallas'")
-    if engine == "pallas":
-        from ..core.device import interpret_mode
-        from ..kernels.fused_alloc_eval import PALLAS_ON_TPU
-
-        if not interpret_mode():
-            raise NotImplementedError(PALLAS_ON_TPU)
 
 
 def _canonical(array: ArrayConfig) -> ArrayConfig:
@@ -374,8 +358,8 @@ class FusedPipeline:
         """Cached ``GreedyEventSchedule`` for one (family, ADC variant).
 
         The greedy families' base latencies are per-variant constants
-        (derived once by ``_stats``), so the entire lock-step greedy
-        collapses into ONE sorted grant-event table per variant that
+        (derived once by ``_stats``), so the entire greedy collapses
+        into ONE sorted grant-event table per variant that
         answers every PE budget with a ``searchsorted`` — exactly (the
         schedule replays the heap order, tie-for-tie; see
         ``core.alloc.greedy.GreedyEventSchedule``).  Rebuilt only when a
@@ -495,7 +479,6 @@ class FusedPipeline:
         chunk: int = 32768,
         return_bank: bool = False,
         need_dups: bool = True,
-        engine: str = "xla",
     ):
         """Evaluate C packed configs in one fused dispatch per chunk.
 
@@ -512,20 +495,9 @@ class FusedPipeline:
         host outputs (the analytic columns never read it back): at 10^6
         configs that single column is gigabytes, and skipping its
         device->host fetch is what keeps the host side flat too.
-
-        ``engine="pallas"`` routes every config through the fused
-        allocate+eval Pallas kernel (``kernels.fused_alloc_eval``): the
-        greedy runs IN-kernel against the per-variant bases (proportional
-        configs ride along at budget 0 with their replicas as warm start)
-        — interpret mode, off-TPU only: on a TPU it raises
-        ``PALLAS_ON_TPU`` (the kernel's float64 contract does not lower
-        to Mosaic).  Results are element-wise identical on the discrete
-        columns and within the rtol 1e-12 contract on floats (pinned by
-        tests/test_fused_dse.py).
         """
         from ..core.precision import x64
 
-        _check_engine(engine)
         tel = get_telemetry()
         with tel.timed("dse.fused.allocate"):
             policies, n_pes, total = self._validate(policies, n_pes)
@@ -549,10 +521,9 @@ class FusedPipeline:
             # its rows.  Proportional replicas are MACs-only constants of
             # the budget (the staged largest-remainder routine, exact); the
             # greedy families replay the per-variant event schedules —
-            # element-wise identical to the lock-step kernel, at a
-            # searchsorted per config instead of a bisection + residual
-            # loop over (C, N) tensors per chunk.  Arrays used come from
-            # each table's own ``spent`` (exact integers, as the replicas).
+            # element-wise identical to the scalar heap, at a searchsorted
+            # per config.  Arrays used come from each table's own
+            # ``spent`` (exact integers, as the replicas).
             r_layer = np.ones((C, self.L))  # rows of family "L" only
             used_f = np.zeros(C)
             distinct = 0
@@ -565,12 +536,6 @@ class FusedPipeline:
                 r_layer[prop] = res.replicas[inv]
                 used_f[prop] = res.spent[inv]
                 distinct += ub.size
-            if engine == "pallas":
-                return self._pallas_eval(
-                    sel, a_idx, kind, budgets, layerwise, zskip, r_layer, total,
-                    int(n_images), float(clock_hz), int(chunk), need_dups,
-                    return_bank,
-                )
             rows_B = np.nonzero(kind == 2)[0]
             r_blk = np.ones((rows_B.size, self.N))  # family "B", rows_B order
             for k, rows_k in ((1, np.nonzero(kind == 1)[0]), (2, rows_B)):
@@ -654,86 +619,6 @@ class FusedPipeline:
             "dse.fused.host_out_bytes", sum(a.nbytes for a in outs.values())
         )
         tel.count("dse.fused.chunks", n_chunks)
-        outs["arrays_total"] = total
-        outs["layerwise"] = layerwise
-        outs["zskip"] = zskip
-        if return_bank:
-            outs["bank"] = np.asarray(self._stats(return_bank=True)[-1])
-        return outs
-
-    def _pallas_eval(
-        self, sel, a_idx, kind, budgets, layerwise, zskip, dups0, total,
-        n_images, clock_hz, chunk, need_dups, return_bank,
-    ):
-        """``engine="pallas"`` body: both greedy families flattened onto the
-        shared unit axis and pushed through ``kernels.fused_alloc_eval`` —
-        greedy + scatter + eval in one grid step per config block.
-        Proportional configs enter at budget 0 with their host-precomputed
-        replicas as the warm start (the greedy is then a no-op), so one
-        kernel serves every supported policy."""
-        from ..core.precision import x64
-
-        from ..kernels.fused_alloc_eval import fused_alloc_eval
-        from .engine import flat_unit_map
-
-        stats = self._stats()
-        banks = stats[:5]
-        C = budgets.shape[0]
-        outs = {
-            "total_cycles": np.zeros(C),
-            "layer_cycles": np.zeros((C, self.L)),
-            "layer_utilization": np.zeros((C, self.L)),
-        }
-        if need_dups:
-            outs["dups_lb"] = np.zeros((C, self.L, self.B))
-        used_f = np.zeros(C)
-        fams = (
-            ("L", np.nonzero(kind != 2)[0], np.asarray(stats[5]),
-             self.layer_arrays, flat_unit_map(self.L, self.B)),
-            ("B", np.nonzero(kind == 2)[0], np.asarray(stats[6]),
-             self.cost_blk, flat_unit_map(self.L, self.B, self.l_idx, self.blk_idx)),
-        )
-        with x64():
-            for fam, rows, base, cost, umap in fams:
-                if rows.size == 0:
-                    continue
-                r0 = np.ones((rows.size, base.shape[1]))
-                bud = budgets[rows].copy()
-                if fam == "L":
-                    isprop = kind[rows] == 0
-                    r0[isprop] = dups0[rows[isprop]]
-                    bud[isprop] = 0.0
-                csize = min(int(chunk), rows.size)
-                for j0 in range(0, rows.size, csize):
-                    part = rows[j0 : j0 + csize]
-                    sl = slice(j0, j0 + part.size)
-                    T, _, layer_T, util, r, _ = fused_alloc_eval(
-                        base, cost, umap, banks, self.b_mask, self.ppi,
-                        self.width, self.layer_arrays, bud[sl], a_idx[part],
-                        sel[part], layerwise[part], r0[sl],
-                        n_images=n_images, clock_hz=clock_hz,
-                        block_configs=min(csize, 128),
-                    )
-                    outs["total_cycles"][part] = np.asarray(T)
-                    outs["layer_cycles"][part] = np.asarray(layer_T)
-                    outs["layer_utilization"][part] = np.asarray(util)
-                    r = np.asarray(r)
-                    if fam == "L":
-                        used_f[part] = (r - 1.0) @ self.layer_arrays
-                        if need_dups:
-                            outs["dups_lb"][part] = np.broadcast_to(
-                                r[:, :, None], (part.size, self.L, self.B)
-                            )
-                    else:
-                        used_f[part] = ((r - 1.0) * cost).sum(axis=1)
-                        if need_dups:
-                            d = np.ones((part.size, self.L, self.B))
-                            d[:, self.l_idx, self.blk_idx] = r
-                            outs["dups_lb"][part] = d
-        outs["images_per_sec"] = images_per_sec(
-            outs["total_cycles"], n_images, clock_hz
-        )
-        outs["arrays_used"] = self.base_arrays + used_f.astype(np.int64)
         outs["arrays_total"] = total
         outs["layerwise"] = layerwise
         outs["zskip"] = zskip
@@ -960,8 +845,6 @@ def run_fused_sweep(
     fabric: FabricEval | None = None,
     shard_devices: bool = False,
     chunk: int = 32768,
-    chunk_size: int | None = None,
-    engine: str = "xla",
 ) -> SweepResult:
     """Drop-in fused counterpart of ``run_sweep(engine="batch")``.
 
@@ -969,19 +852,14 @@ def run_fused_sweep(
     shared per-ADC bank stacks once, then streams the whole (ADC x policy
     x PE-budget) config tensor through ONE fused allocate+eval dispatch
     per chunk — no host round-trips, peak memory bounded by the chunk
-    (``chunk_size``, alias of ``chunk``; tilings are element-wise
-    identical, pinned by tests/test_fused_dse.py) — optionally followed
-    by the fused virtual-time stage for the latency columns.  Without a
+    (tilings are element-wise identical, pinned by tests/test_fused_dse.py)
+    — optionally followed by the fused virtual-time stage for the latency
+    columns.  Without a
     fabric stage the per-config replica tensors are never fetched to the
     host (``need_dups=False`` inside), so a 10^6-config analytic sweep
     holds only (C,)/(C, L) columns.  Results are element-wise identical
     to the staged path.  ``latency_aware`` points are rejected — that
-    policy is load-coupled and stays staged.  ``engine="pallas"`` routes
-    the analytic stage through the fused allocate+eval Pallas kernel (see
-    ``FusedPipeline.__call__``)."""
-    _check_engine(engine)
-    if chunk_size is not None:
-        chunk = int(chunk_size)
+    policy is load-coupled and stays staged."""
     C = len(points)
     out = {
         name: np.zeros(C)
@@ -1014,7 +892,7 @@ def run_fused_sweep(
             t0 = time.perf_counter()
             res = pipe(
                 a_idx, pols, pes, n_images=n_images, chunk=chunk,
-                need_dups=fabric is not None, engine=engine,
+                need_dups=fabric is not None,
             )
             out["total_cycles"][idx] = res["total_cycles"]
             out["images_per_sec"][idx] = res["images_per_sec"]

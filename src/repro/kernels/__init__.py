@@ -1,17 +1,6 @@
-"""Pallas TPU kernels (validated on CPU via interpret=True)."""
-from . import ops, ref
-from .bitplane_profile import bitplane_block_profile, bitplane_profile
-from .flash_attention import flash_attention
-from .fused_alloc_eval import fused_alloc_eval
-from .ssd_scan import ssd_chunk
-from .zskip_matmul import zskip_matmul
-__all__ = [
-    "ops",
-    "ref",
-    "bitplane_block_profile",
-    "bitplane_profile",
-    "flash_attention",
-    "fused_alloc_eval",
-    "ssd_chunk",
-    "zskip_matmul",
-]
+"""Pallas TPU kernels (validated on CPU via interpret=True).
+
+Import each kernel from its own submodule (``repro.kernels.bitplane_profile``,
+``repro.kernels.ops``, ...): the package loads none of them, so the CIM path
+does not pull in the attention and state-space kernels.
+"""

@@ -3,7 +3,9 @@ proportional_allocate edge cases — the online re-allocation path — plus
 the ``greedy_event_schedule`` exactness contract (the static grant-event
 table the fused DSE pipeline replays instead of re-running the greedy).
 
-No hypothesis dependency: these must run in the minimal environment."""
+These must run in the minimal environment: the one hypothesis property at
+the end of the event-schedule section is defined only when hypothesis is
+installed."""
 
 import numpy as np
 import pytest
@@ -156,6 +158,25 @@ def test_event_schedule_zero_and_tiny_budgets():
     np.testing.assert_array_equal(got.leftover, [0.0, 2.0])
 
 
+def test_event_schedule_budget_zero_keeps_warm_start():
+    """Budget 0 returns a multi-unit warm start untouched, with nothing
+    spent or left over — from an empty table and from a full one."""
+    rng = np.random.default_rng(5)
+    for trial in range(20):
+        n = int(rng.integers(2, 16))
+        base = rng.integers(1, 500, size=n).astype(np.float64)
+        cost = rng.integers(1, 6, size=n).astype(np.float64)
+        r0 = rng.integers(1, 6, size=n).astype(np.int64)
+        for W in (0.0, float(rng.integers(10, 200))):
+            sched = greedy_event_schedule(base, cost, W, initial_replicas=r0)
+            got = sched.replicas_at(np.zeros(3))
+            np.testing.assert_array_equal(
+                got.replicas, np.broadcast_to(r0, (3, n)), err_msg=f"trial {trial}"
+            )
+            np.testing.assert_array_equal(got.spent, np.zeros(3))
+            np.testing.assert_array_equal(got.leftover, np.zeros(3))
+
+
 def _replicas_at_walk(sched, budgets):
     """The incremental walk ``replicas_at`` answered with before its
     segmented count: one ``bincount`` and one snapshot per distinct stop."""
@@ -224,6 +245,56 @@ def test_replicas_at_equals_incremental_walk(case):
         ):
             assert g.dtype == w.dtype, f"trial {trial} {name}"
             np.testing.assert_array_equal(g, w, err_msg=f"trial {trial} {name}")
+
+
+try:
+    from hypothesis import given, settings, strategies as st
+
+    @st.composite
+    def _problem(draw, max_units=8):
+        n = draw(st.integers(1, max_units))
+        # small integer pools force cross-unit priority ties, the regime
+        # where heap tie order (lowest unit index first) is observable
+        base = np.array(
+            draw(st.lists(st.integers(1, 12), min_size=n, max_size=n)),
+            dtype=np.float64,
+        )
+        cost = np.array(
+            draw(st.lists(st.integers(1, 4), min_size=n, max_size=n)),
+            dtype=np.float64,
+        )
+        r0 = (
+            np.array(
+                draw(st.lists(st.integers(1, 3), min_size=n, max_size=n)),
+                dtype=np.int64,
+            )
+            if draw(st.booleans())
+            else None
+        )
+        budgets = np.array(
+            draw(st.lists(st.integers(0, 40), min_size=1, max_size=6)),
+            dtype=np.float64,
+        )
+        return base, cost, r0, budgets
+
+    @given(_problem())
+    @settings(max_examples=60, deadline=None)
+    def test_event_schedule_matches_scalar_heap(problem):
+        base, cost, r0, budgets = problem
+        sched = greedy_event_schedule(
+            base, cost, float(budgets.max()), initial_replicas=r0
+        )
+        got = sched.replicas_at(budgets)
+        for i, b in enumerate(budgets):
+            want = greedy_allocate(base, cost, float(b), initial_replicas=r0)
+            np.testing.assert_array_equal(
+                got.replicas[i], want.replicas, err_msg=f"budget {b}"
+            )
+            assert got.spent[i] == want.spent
+            assert got.leftover[i] == want.leftover
+
+except ImportError:  # pragma: no cover - optional dev dep
+    pass
 
 
 # ------------------------------------------------------- proportional edges

@@ -1,5 +1,6 @@
 """Property tests for the DSE building blocks: the blockwise flatten /
-unflatten round-trip and the batched greedy allocator vs the scalar heap."""
+unflatten round-trip and the batched greedy allocator (the event schedule,
+``greedy_event_schedule(...).replicas_at``) vs the scalar heap."""
 
 import numpy as np
 import pytest
@@ -9,14 +10,13 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core.alloc.greedy import (
     greedy_allocate,
-    greedy_allocate_batch,
+    greedy_event_schedule,
     proportional_allocate,
     proportional_allocate_batch,
 )
 from repro.core.cim import LayerSpec, NetworkSpec
 from repro.core.cim.simulate import blockwise_units, split_block_dups
 
-# fixed (C, N) so every hypothesis example reuses one compiled jnp kernel
 N_UNITS = 16
 N_CONFIGS = 4
 
@@ -63,6 +63,14 @@ def test_blockwise_units_split_round_trip(spec, seed):
 
 
 # ---------------------------------------------------- batched greedy == scalar
+def _schedule_at(base, cost, budgets, initial_replicas=None):
+    """The batched greedy: one event schedule answering every budget."""
+    budgets = np.asarray(budgets, dtype=np.float64)
+    return greedy_event_schedule(
+        base, cost, float(budgets.max()), initial_replicas=initial_replicas
+    ).replicas_at(budgets)
+
+
 def _units(draw_ints, draw_floats):
     return st.tuples(
         st.lists(draw_floats, min_size=N_UNITS, max_size=N_UNITS),
@@ -78,7 +86,7 @@ def test_greedy_batch_matches_scalar_loop(args):
     base = np.asarray(lats)
     cost = np.asarray(costs, dtype=np.float64)
     budgets = np.asarray(budgets, dtype=np.float64)
-    batch = greedy_allocate_batch(base, cost, budgets)
+    batch = _schedule_at(base, cost, budgets)
     for c, budget in enumerate(budgets):
         ref = greedy_allocate(base, cost, budget)
         np.testing.assert_array_equal(batch.replicas[c], ref.replicas)
@@ -97,9 +105,7 @@ def test_greedy_batch_warm_start_matches_scalar(args, r0):
     base = np.asarray(lats)
     cost = np.asarray(costs, dtype=np.float64)
     r0 = np.asarray(r0, dtype=np.int64)
-    batch = greedy_allocate_batch(
-        base, cost, np.asarray(budgets, dtype=np.float64), initial_replicas=r0
-    )
+    batch = _schedule_at(base, cost, budgets, initial_replicas=r0)
     for c, budget in enumerate(budgets):
         ref = greedy_allocate(base, cost, float(budget), initial_replicas=r0)
         np.testing.assert_array_equal(batch.replicas[c], ref.replicas)
@@ -133,26 +139,26 @@ def test_proportional_batch_matches_scalar_loop(args):
 
 def test_greedy_batch_tie_breaking_matches_heap():
     """Equal latencies and power-of-two ratios — the adversarial tie cases
-    for the bisection bulk phase — still match the scalar heap exactly."""
+    for the event order — still match the scalar heap exactly."""
     base = np.array([4.0, 2.0, 2.0, 1.0, 1.0, 8.0])
     cost = np.array([1.0, 2.0, 1.0, 1.0, 3.0, 2.0])
     for budget in range(0, 30):
-        batch = greedy_allocate_batch(base, cost, np.array([float(budget)]))
+        batch = _schedule_at(base, cost, [float(budget)])
         ref = greedy_allocate(base, cost, float(budget))
         np.testing.assert_array_equal(batch.replicas[0], ref.replicas)
 
 
 def test_greedy_batch_validation():
     with pytest.raises(ValueError, match="strictly positive"):
-        greedy_allocate_batch([1.0, 2.0], [1.0, 0.0], [5.0])
+        _schedule_at([1.0, 2.0], [1.0, 0.0], [5.0])
     with pytest.raises(ValueError, match="base_latency"):
-        greedy_allocate_batch([1.0, 2.0], [1.0, 1.0, 1.0], [5.0])
+        _schedule_at([1.0, 2.0], [1.0, 1.0, 1.0], [5.0])
     with pytest.raises(ValueError, match="at least one replica"):
-        greedy_allocate_batch([1.0, 2.0], [1.0, 1.0], [5.0], initial_replicas=[0, 1])
+        _schedule_at([1.0, 2.0], [1.0, 1.0], [5.0], initial_replicas=[0, 1])
 
 
 def test_greedy_batch_empty_units():
-    res = greedy_allocate_batch(np.zeros(0), np.zeros(0), [7.0, 0.0])
+    res = _schedule_at(np.zeros(0), np.zeros(0), [7.0, 0.0])
     assert res.replicas.shape == (2, 0)
     np.testing.assert_array_equal(res.leftover, [7.0, 0.0])
     np.testing.assert_array_equal(res.makespan, [0.0, 0.0])  # (C,) like scalar

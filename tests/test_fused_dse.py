@@ -3,8 +3,9 @@ derivation -> allocation -> evaluation, ``dse/fused.py``) against the
 staged path on pinned ResNet18 + VGG11 grids.
 
 The contract (documented in ``dse/fused.py``): DISCRETE columns — replica
-tensors, arrays used/total, chip crossings — are EXACTLY equal (the
-allocators run the same kernel body on bit-equal integer-cycle inputs).
+tensors, arrays used/total, chip crossings — are EXACTLY equal (both paths
+answer the greedy from the same event schedule on bit-equal integer-cycle
+inputs).
 Float-derived columns — total cycles, throughput, utilization, latency
 percentiles — are compared at rtol 1e-12: the staged and fused evaluators
 are different XLA programs, and cross-compilation op-fusion can wobble the
@@ -13,10 +14,13 @@ in 24, ~2e-16 relative; ``busy_sum`` additionally sums rounded means in
 backend-chosen order).  1e-12 is four orders looser than that wobble and
 tight enough that any real formula drift fails.
 
-Also pinned here: sharded (``shard_map_batch``) vs plain fused identity,
-and the fused pipeline's declared limits (latency_aware rejected,
+Also pinned here: the fused columns against the scalar ``allocate`` +
+``simulate`` per config, sharded (``shard_map_batch``) vs plain fused
+identity, and the fused pipeline's declared limits (latency_aware rejected,
 infeasible budgets rejected).
 """
+
+import functools
 
 import numpy as np
 import pytest
@@ -177,30 +181,60 @@ def _packed_grid(net="vgg11", pols=POLS, pes=(300, 557, 800)):
     )
 
 
-def test_pallas_engine_matches_xla():
-    """engine="pallas" (the fused allocate+eval kernel, interpret mode
-    off-TPU) against the XLA path: discrete columns — replica tensors,
-    arrays used — exactly equal, floats within the rtol 1e-12 contract."""
-    pipe = get_fused_pipeline("vgg11", DEFAULT_ARRAY, (6, 8))
+# nine budgets: 72 family-"L" rows and 18 family-"B" rows, so chunks of 8,
+# 21 and 64 each leave a ragged last chunk in at least one family
+_ORACLE_PES = (300, 357, 420, 557, 640, 800, 901, 1024, 1200)
+
+
+@functools.lru_cache(maxsize=1)
+def _scalar_oracle(adcs):
+    """Scalar ``allocate`` + ``simulate`` for every row of the oracle grid."""
+    from repro.core.cim import allocate, simulate
+
     a_idx, pols, pes = _packed_grid(
-        pols=POLS + ("weight_blockflow",), pes=(300, 557, 800)
+        pols=POLS + ("weight_blockflow",), pes=_ORACLE_PES
     )
-    ref = pipe(a_idx, pols, pes, need_dups=True)
-    got = pipe(a_idx, pols, pes, need_dups=True, engine="pallas")
-    for k in ("arrays_used", "arrays_total", "layerwise", "zskip", "dups_lb"):
-        np.testing.assert_array_equal(ref[k], got[k], err_msg=k)
-    for k in (
-        "total_cycles", "images_per_sec", "layer_cycles", "layer_utilization"
-    ):
-        np.testing.assert_allclose(
-            ref[k], got[k], rtol=ULP_RTOL, atol=0, err_msg=k
+    rows = []
+    for a, pol, n in zip(a_idx, pols, pes):
+        spec, prof = get_profiled(
+            "vgg11", DEFAULT_ARRAY.variant(adc_bits=adcs[a])
         )
+        alloc = allocate(spec, prof, pol, int(n))
+        rows.append((spec, alloc, simulate(spec, prof, alloc, n_images=64)))
+    return (a_idx, pols, pes), rows
 
 
-def test_unknown_engine_is_rejected():
-    pipe = get_fused_pipeline("vgg11", DEFAULT_ARRAY, (6,))
-    with pytest.raises(ValueError, match="engine"):
-        pipe(np.zeros(1, np.int32), ["blockwise"], [600], engine="cuda")
+@pytest.mark.parametrize("chunk", [8, 21, 64])
+def test_fused_columns_match_scalar_simulate(chunk):
+    """The fused program (the one the chip runs) against the scalar
+    ``allocate`` + ``simulate`` per config, through ragged last chunks:
+    replicas and arrays used exactly equal, floats within rtol 1e-12."""
+    adcs = (6, 8)
+    pipe = get_fused_pipeline("vgg11", DEFAULT_ARRAY, adcs)
+    (a_idx, pols, pes), rows = _scalar_oracle(adcs)
+    got = pipe(a_idx, pols, pes, chunk=chunk, need_dups=True)
+    for c, (spec, alloc, sim) in enumerate(rows):
+        msg = f"chunk={chunk} row {c} {pols[c]}"
+        for li, layer in enumerate(spec.layers):
+            want = (
+                np.full(layer.n_blocks, alloc.layer_dups[li])
+                if alloc.layer_dups is not None
+                else alloc.block_dups[li]
+            )
+            np.testing.assert_array_equal(
+                got["dups_lb"][c, li, : layer.n_blocks], want, err_msg=msg
+            )
+        assert got["arrays_used"][c] == alloc.arrays_used, msg
+        assert got["arrays_total"][c] == alloc.arrays_total, msg
+        for k, want in (
+            ("total_cycles", sim.total_cycles),
+            ("images_per_sec", sim.images_per_sec),
+            ("layer_cycles", sim.layer_cycles),
+            ("layer_utilization", sim.layer_utilization),
+        ):
+            np.testing.assert_allclose(
+                got[k][c], want, rtol=ULP_RTOL, atol=0, err_msg=f"{msg} {k}"
+            )
 
 
 @pytest.mark.parametrize("chunk", [1, 5, 10**6])

@@ -7,7 +7,6 @@ import pytest
 
 from repro.kernels import ops, ref
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.fused_alloc_eval import fused_alloc_eval
 from repro.kernels.ssd_scan import ssd_chunk
 from repro.kernels.zskip_matmul import zskip_matmul
 
@@ -84,93 +83,6 @@ def test_flash_attention_op_matches_model_sdpa():
     got = ops.flash_attention_op(q, k, v, causal=True)
     want = _sdpa(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4)
-
-
-# -------------------------------------------------------- fused_alloc_eval
-def _fused_problem(seed=0, a=3, n=9, l=4, b=5, c=21):
-    """Random fused allocate+eval problem with integer cycle statistics
-    (the real banks are integer-valued float64) and tie-heavy bases."""
-    rng = np.random.default_rng(seed)
-    v = 2 * a
-    base = rng.integers(40, 400, size=(a, n)).astype(np.float64)
-    base[:, : n // 2] = base[:, n // 2 : n // 2 + n // 2]  # force grant ties
-    cost = rng.integers(1, 5, size=n).astype(np.float64)
-    # random one-hot partition of the (l, b) cells onto n units
-    owner = rng.integers(0, n, size=(l, b))
-    umap = np.zeros((n, l, b))
-    umap[owner, np.arange(l)[:, None], np.arange(b)[None, :]] = 1.0
-    banks = (
-        rng.integers(1, 200, size=(v, l, b)).astype(np.float64),
-        rng.integers(200, 400, size=(v, l, b)).astype(np.float64),
-        rng.integers(1, 200, size=(v, l)).astype(np.float64),
-        rng.integers(200, 400, size=(v, l)).astype(np.float64),
-        rng.integers(1, 100, size=(v, l)).astype(np.float64),
-    )
-    b_mask = np.ones((l, b), dtype=bool)
-    b_mask[1, b - 1 :] = False
-    ppi = rng.integers(1, 30, size=l).astype(np.float64)
-    width = rng.integers(1, 4, size=l).astype(np.float64)
-    larr = rng.integers(1, 8, size=l).astype(np.float64)
-    budgets = rng.integers(0, 60, size=c).astype(np.float64)
-    budgets[0] = 0.0  # the proportional budget-0 ride-along
-    a_idx = rng.integers(0, a, size=c).astype(np.int32)
-    sel = (a_idx + a * rng.integers(0, 2, size=c)).astype(np.int32)
-    lw = rng.integers(0, 2, size=c).astype(bool)
-    r0 = rng.integers(1, 4, size=(c, n)).astype(np.float64)
-    return base, cost, umap, banks, b_mask, ppi, width, larr, budgets, a_idx, sel, lw, r0
-
-
-@pytest.mark.parametrize("block_configs", [8, 21, 64])
-def test_fused_alloc_eval_matches_oracles(block_configs):
-    """Interpret-mode smoke: replicas bit-equal to ``greedy_allocate_batch``
-    (same kernel body — warm starts, ties, budget 0 included) and eval
-    columns equal to the scalar ``_eval_kernel`` per config.  The block
-    grid pads by repeating config 0; every tiling must agree."""
-    from repro.core.precision import x64
-
-    from repro.core.alloc.greedy import greedy_allocate_batch
-    from repro.core.cim.simulate import _eval_kernel
-
-    (base, cost, umap, banks, b_mask, ppi, width, larr,
-     budgets, a_idx, sel, lw, r0) = _fused_problem()
-    with x64():
-        T, ips, layer_T, util, r, rem = fused_alloc_eval(
-            base, cost, umap, banks, b_mask, ppi, width, larr,
-            budgets, a_idx, sel, lw, r0,
-            n_images=16, clock_hz=1e9, block_configs=block_configs,
-        )
-    want = greedy_allocate_batch(
-        base[a_idx], cost, budgets, initial_replicas=r0
-    )
-    np.testing.assert_array_equal(np.asarray(r), want.replicas)
-    np.testing.assert_allclose(np.asarray(rem), want.leftover, rtol=0, atol=0)
-    for i in range(budgets.size):
-        dups = 1.0 + np.tensordot(want.replicas[i] - 1.0, umap, axes=1)
-        tT, tips, tlt, tu = _eval_kernel(
-            np, *banks, b_mask, ppi, width, larr, dups, bool(lw[i]),
-            16, 1e9, sel=int(sel[i]),
-        )
-        np.testing.assert_allclose(np.asarray(T)[i], tT, rtol=1e-12)
-        np.testing.assert_allclose(np.asarray(ips)[i], tips, rtol=1e-12)
-        np.testing.assert_allclose(np.asarray(layer_T)[i], tlt, rtol=1e-12)
-        np.testing.assert_allclose(np.asarray(util)[i], tu, rtol=1e-12)
-
-
-def test_fused_alloc_eval_budget_zero_is_warm_start_identity():
-    """Budget 0 must return the warm start untouched — the contract that
-    lets proportional configs ride through the greedy kernel as no-ops."""
-    from repro.core.precision import x64
-
-    (base, cost, umap, banks, b_mask, ppi, width, larr,
-     budgets, a_idx, sel, lw, r0) = _fused_problem(seed=1)
-    budgets[:] = 0.0
-    with x64():
-        *_, r, rem = fused_alloc_eval(
-            base, cost, umap, banks, b_mask, ppi, width, larr,
-            budgets, a_idx, sel, lw, r0,
-        )
-    np.testing.assert_array_equal(np.asarray(r), r0)
-    np.testing.assert_array_equal(np.asarray(rem), np.zeros_like(budgets))
 
 
 # -------------------------------------------------------------- ssd_chunk

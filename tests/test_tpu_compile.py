@@ -160,17 +160,3 @@ def test_fleet_stream_runner_compiles(one_chip):
         )
         compiled = fn.lower(*args).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 16e9
-
-
-def test_pallas_engine_raises_on_tpu(monkeypatch):
-    """On a TPU, engine="pallas" refuses with the reason, before any work."""
-    from repro.dse import design_grid, run_fused_sweep
-    from repro.kernels.fused_alloc_eval import PALLAS_ON_TPU, fused_alloc_eval
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    pts = design_grid(networks=("resnet18",), pe_multipliers=(1.0,))
-    with pytest.raises(NotImplementedError, match="cannot run on a TPU") as e:
-        run_fused_sweep(pts, engine="pallas")
-    assert str(e.value) == PALLAS_ON_TPU
-    with pytest.raises(NotImplementedError, match="no 64-bit"):
-        fused_alloc_eval(*([None] * 13))
