@@ -382,15 +382,21 @@ class FusedPipeline:
         return sched
 
     # --------------------------------------------------------- traced program
-    def _fn(self, fam: str, n_images: int, clock_hz: float):
+    def _fn(self, fam: str, n_images: int, clock_hz: float, need_dups: bool):
         """Per-chunk program for one replica FAMILY: ``"L"`` (per-layer
         replica vectors — the proportional and perf_layerwise kinds) or
         ``"B"`` (per-block-unit vectors — blockwise).  With allocation
         answered by the shared event schedules, the traced program is pure
         scatter + vmapped eval; the bank stacks ride in as unbatched
         closures and each config gathers its variant by one scalar ``sel``
-        inside ``_eval_kernel``."""
-        key = (fam, n_images, clock_hz)
+        inside ``_eval_kernel``.
+
+        Returns a tuple whose first entry is one packed (C, 1+L) float64
+        block: total cycles, then the per-layer utilization, so the host
+        fetches a chunk in one copy.  With ``need_dups`` the (C, L, B)
+        replica tensor follows as a second entry.  Packing moves values
+        and computes none."""
+        key = (fam, n_images, clock_hz, need_dups)
         if key in self._compiled:
             return self._compiled[key]
         import functools
@@ -420,13 +426,14 @@ class FusedPipeline:
                 n_images=n_images,
                 clock_hz=clock_hz,
             )
-            T, _, layer_T, util = jax.vmap(
+            T, _, _, util = jax.vmap(
                 lambda s, d, lw: eval_one(
                     mean_s, max_s, pmn_s, pmx_s, busy_s,
                     dups_lb=d, layerwise=lw, sel=s,
                 )
             )(sel, dups_lb, layerwise)
-            return T, layer_T, util, dups_lb
+            packed = jnp.concatenate([T[:, None], util], axis=1)
+            return (packed, dups_lb) if need_dups else (packed,)
 
         stats = self._stats()[:5]
         if self.shard:
@@ -439,14 +446,10 @@ class FusedPipeline:
                 functools.partial(fused, stats)
             )
         else:
-            # donate the (C, L) replica operand where an output of the same
-            # shape exists (layer_T / util): the chunked driver streams
-            # fresh chunks through one program, so XLA reuses the buffer
-            # instead of growing the live set per dispatch
-            donate = (3,) if fam == "L" else ()
-            self._compiled[key] = functools.partial(
-                jax.jit(fused, donate_argnums=donate), stats
-            )
+            # no donate_argnums: no output has the replica operand's shape
+            # (the packed block is (C, 1+L)), so XLA could alias nothing
+            # and would warn on every dispatch
+            self._compiled[key] = functools.partial(jax.jit(fused), stats)
         return self._compiled[key]
 
     def _validate(self, policies, n_pes):
@@ -483,7 +486,7 @@ class FusedPipeline:
         """Evaluate C packed configs in one fused dispatch per chunk.
 
         Returns a dict of numpy columns (total_cycles, images_per_sec,
-        layer_cycles, layer_utilization, dups_lb, layerwise, zskip,
+        layer_utilization, dups_lb, layerwise, zskip,
         arrays_used, arrays_total) plus ``bank`` (A, L, S, B) float64 when
         ``return_bank`` — element-wise identical to the staged
         ``allocate_batch`` + ``BatchSimulator`` outputs.
@@ -495,6 +498,10 @@ class FusedPipeline:
         host outputs (the analytic columns never read it back): at 10^6
         configs that single column is gigabytes, and skipping its
         device->host fetch is what keeps the host side flat too.
+
+        Each chunk comes back in one device->host copy of a packed block
+        (total cycles and per-layer utilization), plus one copy of its
+        replica tensor when ``need_dups``.
         """
         from ..core.precision import x64
 
@@ -560,7 +567,6 @@ class FusedPipeline:
 
         outs = {
             "total_cycles": np.zeros(C),
-            "layer_cycles": np.zeros((C, self.L)),
             "layer_utilization": np.zeros((C, self.L)),
         }
         if need_dups:
@@ -570,7 +576,7 @@ class FusedPipeline:
             for fam, rows, r_fam in (("L", rows_L, r_layer), ("B", rows_B, r_blk)):
                 if rows.size == 0:
                     continue
-                fn = self._fn(fam, int(n_images), float(clock_hz))
+                fn = self._fn(fam, int(n_images), float(clock_hz), need_dups)
                 csize = min(int(chunk), rows.size)
                 csize_max = max(csize_max, csize)
                 for j0 in range(0, rows.size, csize):
@@ -592,16 +598,14 @@ class FusedPipeline:
                                 r_take = np.concatenate(
                                     [r_take, np.repeat(r_take[:1], pad, axis=0)]
                                 )
-                        T, layer_T, util, dups = fn(
-                            sel[take], layerwise[take], r_take
-                        )
+                        on_dev = fn(sel[take], layerwise[take], r_take)
                     with tel.timed("dse.fused.fetch", configs=part.size):
                         n = part.size
-                        outs["total_cycles"][part] = np.asarray(T)[:n]
-                        outs["layer_cycles"][part] = np.asarray(layer_T)[:n]
-                        outs["layer_utilization"][part] = np.asarray(util)[:n]
+                        packed = np.asarray(on_dev[0])[:n]
+                        outs["total_cycles"][part] = packed[:, 0]
+                        outs["layer_utilization"][part] = packed[:, 1:]
                         if need_dups:
-                            outs["dups_lb"][part] = np.asarray(dups)[:n]
+                            outs["dups_lb"][part] = np.asarray(on_dev[1])[:n]
                     n_chunks += 1
         outs["images_per_sec"] = images_per_sec(
             outs["total_cycles"], n_images, clock_hz
@@ -609,12 +613,14 @@ class FusedPipeline:
         outs["arrays_used"] = self.base_arrays + used_f.astype(np.int64)
         # chunking telemetry: the live device set per dispatch is one tile —
         # the (csize, L, B) replica tensor dominates — never the full C
-        # (the peak-memory smoke in tests/test_fused_dse.py reads these)
+        # (the peak-memory smoke in tests/test_fused_dse.py reads these).
+        # Per config: the replica tensor (and its output copy with
+        # need_dups), the replica operand (at most N), the packed 1+L
+        # outputs, sel and layerwise.
         tel.gauge("dse.fused.chunk_configs", csize_max)
-        tel.gauge(
-            "dse.fused.chunk_device_bytes",
-            csize_max * (2 * self.L * self.B + self.N + 2 * self.L + 3) * 8,
-        )
+        dups_values = (2 if need_dups else 1) * self.L * self.B
+        per_config = dups_values + self.N + self.L + 3
+        tel.gauge("dse.fused.chunk_device_bytes", csize_max * per_config * 8)
         tel.gauge(
             "dse.fused.host_out_bytes", sum(a.nbytes for a in outs.values())
         )
@@ -857,9 +863,12 @@ def run_fused_sweep(
     columns.  Without a
     fabric stage the per-config replica tensors are never fetched to the
     host (``need_dups=False`` inside), so a 10^6-config analytic sweep
-    holds only (C,)/(C, L) columns.  Results are element-wise identical
-    to the staged path.  ``latency_aware`` points are rejected — that
-    policy is load-coupled and stays staged."""
+    holds only (C,)/(C, L) columns.  No per-layer cycles are fetched:
+    each chunk comes back in one copy of its total cycles and per-layer
+    utilization, whose mean over layers is taken here, on the host.
+    Results are element-wise identical to the staged path.
+    ``latency_aware`` points are rejected — that policy is load-coupled
+    and stays staged."""
     C = len(points)
     out = {
         name: np.zeros(C)

@@ -229,7 +229,6 @@ def test_fused_columns_match_scalar_simulate(chunk):
         for k, want in (
             ("total_cycles", sim.total_cycles),
             ("images_per_sec", sim.images_per_sec),
-            ("layer_cycles", sim.layer_cycles),
             ("layer_utilization", sim.layer_utilization),
         ):
             np.testing.assert_allclose(
@@ -262,7 +261,9 @@ def test_chunking_bounds_device_footprint():
     C = len(pols)
     n_L = int(np.sum(pols != "blockwise"))
     n_B = C - n_L
-    per_config = (2 * pipe.L * pipe.B + pipe.N + 2 * pipe.L + 3) * 8
+    # need_dups=False: one (L, B) replica tensor, the replica operand (at
+    # most N), the packed 1+L outputs, sel and layerwise
+    per_config = (pipe.L * pipe.B + pipe.N + pipe.L + 3) * 8
     with telemetry_session() as tel:
         pipe(a_idx, pols, pes, chunk=4, need_dups=False)
         snap = tel.snapshot()
@@ -279,6 +280,81 @@ def test_chunking_bounds_device_footprint():
         == max(n_L, n_B) * per_config
     )
     assert snap_full["counters"]["dse.fused.chunks"] == 2  # one per family
+
+
+@pytest.mark.parametrize(
+    "chunk, fabric",
+    [
+        (5, None),
+        (10**6, None),
+        (32768, FabricEval(load_frac=0.7, n_requests=30, seed=0)),
+    ],
+    ids=["ragged", "single", "fabric"],
+)
+def test_sweep_columns_equal_a_full_fetch(chunk, fabric):
+    """``run_fused_sweep`` fetches no replicas without a fabric stage; its
+    columns still equal, bit for bit, those of a direct call that fetches
+    every column, and a fabric stage still gets the replicas its
+    percentiles need."""
+    from repro.core.cim.simulate import CLOCK_HZ
+
+    pts = _grid("vgg11")
+    got = run_fused_sweep(pts, chunk=chunk, fabric=fabric)
+    adcs = tuple(sorted({p.array.adc_bits for p in pts}))
+    pipe = get_fused_pipeline("vgg11", DEFAULT_ARRAY, adcs)
+    a_idx = np.array([adcs.index(p.array.adc_bits) for p in pts], dtype=np.int32)
+    ref = pipe(a_idx, [p.policy for p in pts], [p.n_pes for p in pts], need_dups=True)
+    want = {k: ref[k] for k in EXACT_COLS + ("total_cycles", "images_per_sec")}
+    want["mean_utilization"] = ref["layer_utilization"].mean(axis=1)
+    for k, v in want.items():
+        np.testing.assert_array_equal(getattr(got, k), v, err_msg=k)
+    if fabric is None:
+        assert got.p50_cycles is None
+        return
+    gaps = np.random.default_rng(fabric.seed).exponential(1.0, size=fabric.n_requests)
+    rates = fabric.load_frac * ref["images_per_sec"] / CLOCK_HZ
+    pcts = pipe.fabric_percentiles(
+        a_idx, ref["dups_lb"], ref["layerwise"], ref["zskip"],
+        np.cumsum(gaps)[None, :] / rates[:, None], seed=fabric.seed,
+    )
+    np.testing.assert_array_equal(
+        np.stack([got.p50_cycles, got.p95_cycles, got.p99_cycles], axis=1), pcts
+    )
+
+
+def test_need_dups_drops_only_the_replicas():
+    """Without ``need_dups`` the replica tensor is absent from the dict
+    (never zeros), and every other column equals, element for element, a
+    call that fetches it."""
+    pipe = get_fused_pipeline("vgg11", DEFAULT_ARRAY, (6, 8))
+    a_idx, pols, pes = _packed_grid()
+    ref = pipe(a_idx, pols, pes, need_dups=True)
+    got = pipe(a_idx, pols, pes, need_dups=False)
+    assert set(ref) - set(got) == {"dups_lb"}
+    for k in got:
+        np.testing.assert_array_equal(got[k], ref[k], err_msg=k)
+
+
+@pytest.mark.parametrize("need_dups", [False, True])
+@pytest.mark.parametrize("fam", ["L", "B"])
+def test_chunk_program_packs_its_columns(fam, need_dups):
+    """A chunk program returns one (C, 1+L) block of total cycles then
+    per-layer utilization, so the host fetches it in one copy, and the
+    (C, L, B) replica tensor as a second output only with ``need_dups``."""
+    from repro.core.cim.simulate import CLOCK_HZ
+    from repro.core.precision import x64
+
+    pipe = get_fused_pipeline("vgg11", DEFAULT_ARRAY, (6, 8))
+    fn = pipe._fn(fam, 64, CLOCK_HZ, need_dups)
+    c = 3
+    width = pipe.L if fam == "L" else pipe.N
+    with x64():
+        got = fn(np.zeros(c, np.int32), np.zeros(c, bool), np.ones((c, width)))
+        assert pipe._fn(fam, 64, CLOCK_HZ, need_dups) is fn
+    assert [g.shape for g in got] == [(c, 1 + pipe.L)] + (
+        [(c, pipe.L, pipe.B)] if need_dups else []
+    )
+    assert got[0].dtype == np.float64
 
 
 def _alloc_call(pes=(557, 300, 800, 300, 1024)):

@@ -190,7 +190,7 @@ def _module_name(lowered):
 
 def test_xla_module_names_are_those_the_benchmark_readers_match():
     pipe = get_fused_pipeline("vgg11", DEFAULT_ARRAY, (3,))
-    fn = pipe._fn("L", 64, CLOCK_HZ)
+    fn = pipe._fn("L", 64, CLOCK_HZ, need_dups=False)
     c = 4
     with x64():
         lowered = fn.func.lower(

@@ -80,13 +80,14 @@ def test_bitplane_kernel_compiles(one_chip, rows, blocks):
 @pytest.mark.parametrize("fam", ["L", "B"])
 def test_fused_chunk_program_compiles(one_chip, monkeypatch, fam):
     """One fused-DSE chunk (scatter + vmapped eval, float64) for ResNet18
-    at the default 32768-config chunk, both replica families."""
+    at the default 32768-config chunk, both replica families, as the
+    sweep runs it: the packed block out, no replica tensor."""
     from repro.dse import fused
 
     spec = resnet18_imagenet()
     monkeypatch.setattr(fused, "get_captured", lambda *a, **k: _synthetic_capture(spec))
     pipe = fused.FusedPipeline("resnet18", DEFAULT_ARRAY, (2, 4, 8))
-    fn = pipe._fn(fam, 64, CLOCK_HZ)
+    fn = pipe._fn(fam, 64, CLOCK_HZ, need_dups=False)
     c = 32768
     width = pipe.L if fam == "L" else pipe.N
     with x64():
